@@ -182,11 +182,13 @@ def partitioner_ablation(scale: float) -> None:
 
 
 def kernel_micro(quick: bool) -> None:
-    """Pallas kernels (interpret mode on CPU) vs jnp reference."""
+    """Pallas kernels (interpret mode off-TPU) vs jnp reference."""
     from repro.kernels import matrix_ingest, matrix_lookup
     from repro.kernels import ref as kref
+    from repro.kernels.ops import interpret_mode
 
-    _log("\n== kernel_micro (interpret mode — correctness-path timing only) ==")
+    interpret = interpret_mode()
+    _log(f"\n== kernel_micro (interpret={interpret}) ==")
     d, p, w, c = 5, 1, 256, 4096
     rng = np.random.default_rng(0)
     pool = jnp.zeros((d, p, w, w), jnp.int32)
@@ -195,11 +197,11 @@ def kernel_micro(quick: bool) -> None:
     wt = jnp.ones((p, c), jnp.int32)
 
     for name, fn in [
-        ("pallas_matrix_ingest", lambda: matrix_ingest(pool, hi, hj, wt,
-                                                       block_b=256, interpret=True)),
+        ("pallas_matrix_ingest", lambda: matrix_ingest(
+            pool, hi, hj, wt, block_b=256, interpret=interpret)),
         ("jnp_matrix_ingest_ref", lambda: kref.matrix_ingest_ref(pool, hi, hj, wt)),
-        ("pallas_matrix_lookup", lambda: matrix_lookup(pool, hi, hj,
-                                                       block_q=256, interpret=True)),
+        ("pallas_matrix_lookup", lambda: matrix_lookup(
+            pool, hi, hj, block_q=256, interpret=interpret)),
         ("jnp_matrix_lookup_ref", lambda: kref.matrix_lookup_ref(pool, hi, hj)),
     ]:
         fn()  # compile
@@ -228,6 +230,7 @@ def ingest_backends(scale: float, quick: bool,
     import json as _json
 
     from repro.core import kmatrix_accel as kma
+    from repro.kernels.ops import interpret_mode
 
     dataset = "cit-HepPh"
     stream = make_stream(dataset, batch_size=4096, seed=1, scale=scale)
@@ -242,7 +245,7 @@ def ingest_backends(scale: float, quick: bool,
                                 depth=5, seed=3)
     flat = kma.to_flat_layout(kma.empty_like(accel))  # bit-exact twin layout
     _log(f"\n== ingest ({dataset}, {n_batches} batches, {edges} edges, "
-         f"256KB d=5, interpret={jax.default_backend() != 'tpu'}) ==")
+         f"256KB d=5, interpret={interpret_mode()}) ==")
 
     states, backends = {}, {}
     for name, sk, mod in [("flat", flat, kmatrix), ("pallas", accel, kma)]:
@@ -275,7 +278,7 @@ def ingest_backends(scale: float, quick: bool,
         "edges": edges,
         "depth": 5,
         "budget_kb": 256,
-        "interpret": jax.default_backend() != "tpu",
+        "interpret": interpret_mode(),
         "overflow_edges": int(states["pallas"].overflow),
         "backends": backends,
         "bit_exact": bit_exact,
@@ -1121,6 +1124,9 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.launch.host import init_compile_cache
+
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--scale", type=float, default=None,

@@ -330,6 +330,7 @@ def run_serve_bench_concurrent(*, dataset: str = "cit-HepPh",
         "budget_kb": budget_kb,
         "depth": depth,
         "runtime_backend": runtime.backend.name,
+        "worker_placement": runtime.backend.placement,
         "backpressure": backpressure,
         "publish_policy": publish_policy or f"every:{publish_every}",
         "offered_qps": report.offered_qps,
@@ -505,6 +506,7 @@ def run_serve_bench_sharded(*, dataset: str = "cit-HepPh",
         "depth": depth,
         "n_shards": n_shards,
         "runtime_backend": runtime.backend.name,
+        "worker_placement": runtime.backend.placement,
         "backpressure": backpressure,
         "publish_policy": publish_policy or f"every:{publish_every}",
         "offered_qps": report.offered_qps,
@@ -537,6 +539,9 @@ def run_serve_bench_sharded(*, dataset: str = "cit-HepPh",
 
 
 def main() -> None:
+    from repro.launch.host import init_compile_cache
+
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cit-HepPh")
     ap.add_argument("--sketch", default="kmatrix")

@@ -132,6 +132,7 @@ class KMatrixAccel:
         widths = np.asarray([1 << (int(p.width).bit_length() - 1)
                              for p in plan.partitions], dtype=np.int32)
         part_class, part_index, classes, counts = _class_structure(widths)
+        _check_class_widths(classes)
         # offsets are the FLAT layout invariant (cumsum of w_p^2 slabs) even
         # though the class layout never reads them: one route table must
         # serve both layouts, or to_flat_layout / checkpoint interchange
@@ -230,6 +231,17 @@ def _class_structure(widths: np.ndarray):
         part_index[members] = np.arange(len(members))
         counts.append(len(members))
     return part_class, part_index, classes, counts
+
+
+def _check_class_widths(classes) -> None:
+    """Refuse a layout whose widest class the ingest kernel cannot compile."""
+    from repro.kernels.matrix_ingest import MAX_WIDTH
+
+    if max(classes) > MAX_WIDTH:
+        raise ValueError(
+            f"width class {max(classes)} exceeds the widest class the Pallas "
+            f"ingest kernel is built for ({MAX_WIDTH}); use the flat sketch "
+            "backend, a smaller budget or more partitions")
 
 
 # --------------------------------------------------------------- protocol --
@@ -393,6 +405,7 @@ def to_class_layout(sk: KMatrix, *, overflow: jax.Array | int | None = None
             "to_class_layout: route offsets are not the standard cumsum "
             "slab layout; refusing a lossy relayout")
     part_class, part_index, classes, counts = _class_structure(widths)
+    _check_class_widths(classes)
     d = sk.depth
     pools = []
     for c, w_c in enumerate(classes):

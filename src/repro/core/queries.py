@@ -77,9 +77,9 @@ def _build_closure_pallas(adj_layers: jax.Array, n_steps: int,
 
 def closure_backend(backend: str | None = None) -> str:
     """Resolve the closure backend: explicit arg > $REPRO_CLOSURE_BACKEND >
-    platform default (Pallas kernel on TPU, pure jnp elsewhere — the Pallas
-    path still *runs* off-TPU via ``interpret=True``, it is just slower than
-    XLA's fused matmuls, so it is opt-in there)."""
+    platform default (Pallas kernel on TPU, pure jnp elsewhere — off a TPU
+    the Pallas path runs in the Pallas interpreter, which checks results
+    only and is slower than XLA's fused matmuls, so it is opt-in there)."""
     backend = backend or os.environ.get("REPRO_CLOSURE_BACKEND") or (
         "pallas" if jax.default_backend() == "tpu" else "jnp")
     if backend not in ("jnp", "pallas"):

@@ -33,17 +33,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-# jax 0.4.x mis-types the scan inside the searchsorted-based route lookup
-# under shard_map ("Scan carry input and output got mismatched replication
-# types"), and its own error message prescribes check_rep=False.  Scope the
-# workaround to affected versions so newer jax keeps replication checking
-# (the guard that catches e.g. a dropped psum) enabled.
-_CHECK_REP_COMPAT = (
-    {"check_rep": False} if jax.__version__.startswith("0.4.") else {}
-)
 
 from repro.common.hashing import fastrange
 from repro.core.kmatrix import KMatrix
@@ -67,13 +58,12 @@ def make_dp_ingest(sk_template: KMatrix, mesh, axis: str = "data"):
         return new.pool, new.conn
 
     d, pool_size = sk_template.pool.shape
-    return shard_map(
+    return jax.jit(shard_map(
         local_ingest,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None, None), P(axis), P(axis), P(axis)),
         out_specs=(P(axis, None), P(axis, None, None)),
-        **_CHECK_REP_COMPAT,
-    )
+    ))
 
 
 def make_dp_edge_freq(sk_template: KMatrix, mesh, axis: str = "data"):
@@ -87,13 +77,12 @@ def make_dp_edge_freq(sk_template: KMatrix, mesh, axis: str = "data"):
         est = kmatrix.edge_freq(sk, src, dst)
         return est
 
-    return shard_map(
+    return jax.jit(shard_map(
         local_query,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None, None), P(None), P(None)),
         out_specs=P(None),
-        **_CHECK_REP_COMPAT,
-    )
+    ))
 
 
 # ------------------------------------------------------ partition parallel
@@ -123,7 +112,7 @@ def make_pp_ingest(
 ):
     """Partition-parallel ingest under shard_map.
 
-    State layout:每 model shard holds the FULL flat pool buffer but only
+    State layout: each model shard holds the FULL flat pool buffer but only
     writes its owned slabs (memory-lean layouts would slice the pool per
     owner; we keep the flat buffer so estimates stay one gather — the
     unwritten regions are zeros and a psum(model) at query time
@@ -193,9 +182,7 @@ def make_pp_ingest(
         own_s = own[order]
         pos = jnp.arange(b_m, dtype=jnp.int32)
         is_start = jnp.concatenate([jnp.ones(1, bool), own_s[1:] != own_s[:-1]])
-        start = jax.lax.associative_scan(
-            jnp.maximum, jnp.where(is_start, pos, 0)
-        )
+        start = jax.lax.cummax(jnp.where(is_start, pos, 0))
         rank_s = pos - start
         rank = jnp.zeros_like(rank_s).at[order].set(rank_s)
         keep = (rank < cap) & (own < n_model)
@@ -226,7 +213,7 @@ def make_pp_ingest(
         return new.pool, conn_update(conn), dropped
 
     both = data_axes + (model_axis,)
-    fn = shard_map(
+    fn = jax.jit(shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -237,8 +224,7 @@ def make_pp_ingest(
             P(data_axes),
         ),
         out_specs=(P(both, None), P(both, None, None), P()),
-        **_CHECK_REP_COMPAT,
-    )
+    ))
     return fn, owner_np
 
 
@@ -262,10 +248,9 @@ def make_pp_edge_freq(sk_template: KMatrix, mesh, *,
         return kmatrix.edge_freq(sk, src, dst)
 
     both = data_axes + (model_axis,)
-    return shard_map(
+    return jax.jit(shard_map(
         local,
         mesh=mesh,
         in_specs=(P(both, None), P(both, None, None), P(None), P(None)),
         out_specs=P(None),
-        **_CHECK_REP_COMPAT,
-    )
+    ))

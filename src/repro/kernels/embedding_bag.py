@@ -40,7 +40,7 @@ def embedding_bag(
     idx: jax.Array,
     weights: jax.Array | None = None,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """out[b] = sum_f weights[b,f] * table[idx[b,f]].
 

@@ -14,8 +14,9 @@ Two consumption levels:
     capacity factor; a sketch must count EVERY edge, so capacity overflow
     falls back to an exact in-jit scatter (never drops, unlike MoE).
 
-On this CPU container every kernel runs with interpret=True (same dataflow,
-Python-executed kernel body); on real TPUs pass interpret=False.
+Interpret mode is decided per call (``interpret_mode``): kernels compile
+through Mosaic when JAX's default backend is a TPU and run in the Pallas
+interpreter (same dataflow, Python-executed kernel body) anywhere else.
 """
 from __future__ import annotations
 
@@ -34,7 +35,11 @@ from repro.kernels.matrix_lookup import matrix_lookup
 from repro.kernels.reach_closure import reach_step
 from repro.kernels.embedding_bag import embedding_bag  # re-export
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """True unless JAX's default backend is a TPU.  Asked at call time, never
+    at import, so the answer tracks the backend JAX actually started."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_edges(x: jax.Array, block: int, fill=0) -> jax.Array:
@@ -57,9 +62,8 @@ def accel_matrix_ingest(sk: MatrixSketch, batch: EdgeBatch,
     hi = _pad_edges(hi, block_b)[:, None, :]  # [d, 1, C]
     hj = _pad_edges(hj, block_b)[:, None, :]
     wt = _pad_edges(batch.weight, block_b)[None, :]  # [1, C]
-    table = matrix_ingest(
-        sk.table[:, None], hi, hj, wt, block_b=block_b, interpret=_INTERPRET
-    )[:, 0]
+    table = matrix_ingest(sk.table[:, None], hi, hj, wt, block_b=block_b,
+                          interpret=interpret_mode())[:, 0]
     return sk.replace(table=table)
 
 
@@ -68,7 +72,7 @@ def accel_matrix_edge_freq(sk: MatrixSketch, src: jax.Array, dst: jax.Array,
     hi = _pad_edges(fastrange(sk.hashes.mix(src), sk.w), block_q)[:, None, :]
     hj = _pad_edges(fastrange(sk.hashes.mix(dst), sk.w), block_q)[:, None, :]
     est = matrix_lookup(sk.table[:, None], hi, hj, block_q=block_q,
-                        interpret=_INTERPRET)
+                        interpret=interpret_mode())
     return est[0, : src.shape[-1]]
 
 
@@ -83,7 +87,8 @@ def accel_reach_closure(table: jax.Array, *, block: int = 128,
     eye = jnp.eye(wp, dtype=jnp.float32)
     reach = jnp.minimum(adj + eye[None], 1.0)
     steps = n_steps if n_steps is not None else max(1, (w - 1).bit_length())
-    step = functools.partial(reach_step, block=block, interpret=_INTERPRET)
+    step = functools.partial(reach_step, block=block,
+                             interpret=interpret_mode())
     for _ in range(steps):
         reach = jax.vmap(step)(reach)
     return reach[:, :w, :w] > 0.5
@@ -160,7 +165,7 @@ def kmatrix_accel_ingest(sk: KMatrixAccel, batch: EdgeBatch,
         wt_r = jnp.zeros((p_c, capacity), jnp.int32).at[q, slot].add(
             jnp.where(sel, batch.weight, 0), mode="drop")
         pools[c] = matrix_ingest(pools[c], hi_r, hj_r, wt_r,
-                                 block_b=block_b, interpret=_INTERPRET)
+                                 block_b=block_b, interpret=interpret_mode())
 
     # Overflow tail: exact scatter (rare; only when a partition exceeds cap).
     # The tally is surfaced as sk.overflow so capacity regressions show up

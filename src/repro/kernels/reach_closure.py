@@ -36,7 +36,8 @@ def _closure_step_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def reach_step(reach: jax.Array, *, block: int = 256, interpret: bool = True) -> jax.Array:
+def reach_step(reach: jax.Array, *, block: int = 256,
+               interpret: bool) -> jax.Array:
     """One squaring step R <- min(R @ R, 1). reach: f32[w, w], w % block == 0."""
     w = reach.shape[-1]
     assert w % block == 0, (w, block)

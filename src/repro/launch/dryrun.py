@@ -106,7 +106,7 @@ def loop_multiplier_for(arch_name: str) -> int:
 def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
              verbose: bool = True) -> dict:
     from repro.configs.registry import build_cell
-    from repro.launch.mesh import make_production_mesh, use_mesh
+    from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
@@ -123,9 +123,9 @@ def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
                 is_leaf=lambda x: isinstance(x, PartitionSpec),
             )
 
-        # use_mesh (jax.set_mesh when available) — set_mesh installs the
-        # abstract mesh that in-model shard_map/constraints see under jit.
-        with use_mesh(mesh):
+        # set_mesh installs the abstract mesh that in-model
+        # shard_map/constraints see under jit.
+        with jax.set_mesh(mesh):
             jitted = jax.jit(cell.step_fn,
                              in_shardings=to_sharding(cell.in_specs),
                              out_shardings=None if cell.out_specs is None
@@ -192,7 +192,7 @@ def run_sketch_cell(*, multi_pod: bool, mode: str = "a2a",
 
     from repro.core import KMatrix, vertex_stats_from_sample
     from repro.distributed.sketch_parallel import make_pp_ingest
-    from repro.launch.mesh import make_production_mesh, use_mesh
+    from repro.launch.mesh import make_production_mesh
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     rec = {"arch": f"kmatrix-stream-{mode}", "shape": f"ingest_{batch}",
@@ -213,7 +213,7 @@ def run_sketch_cell(*, multi_pod: bool, mode: str = "a2a",
         conn = jax.ShapeDtypeStruct((n_rep * sk.conn.shape[0],)
                                     + sk.conn.shape[1:], jnp.int32)
         edges = jax.ShapeDtypeStruct((batch,), jnp.int32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, owner = make_pp_ingest(sk, mesh, mode=mode)
             lowered = jax.jit(fn, donate_argnums=(0, 1)).lower(
                 pool, conn, edges, edges, edges)

@@ -29,21 +29,6 @@ def make_debug_mesh(n_devices: int | None = None):
     return jax.make_mesh((n // model, model), ("data", "model"))
 
 
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` for jit/shard_map tracing.
-
-    ``jax.set_mesh`` (which also installs the abstract mesh seen by in-model
-    sharding constraints) only exists on newer jax; on older releases the
-    classic ``with mesh:`` resource env is the supported equivalent — our
-    shard_map call sites all pass ``mesh`` explicitly, so the resource env
-    only needs to cover pjit constraint resolution.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def dp_axes(mesh) -> tuple[str, ...]:
     """The data-parallel axis bundle: ('pod','data') on multi-pod meshes."""
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))

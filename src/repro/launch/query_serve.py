@@ -59,6 +59,7 @@ import signal
 import sys
 import time
 
+from repro.launch.host import device_info, init_compile_cache
 from repro.serving import (
     OpenLoopLoadGen,
     QueryEngine,
@@ -68,6 +69,7 @@ from repro.serving import (
     synth_requests,
     warm_bucket_ladder,
 )
+from repro.serving.gates import conservation_verdict
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -385,9 +387,13 @@ def background_serve(args, tenant, engine, requests) -> tuple:
     runtime.join_pumps()  # finish offering the stream, then drain
     final_report = runtime.stop(drain=True)
     tr = final_report[tenant.key.tenant_id]
+    cons = conservation_verdict(tr["published_edges"], tr["dropped_edges"],
+                                tenant.stream.spec.n_edges,
+                                tr["unaccounted_edges"])
     extras = {
         "ingest_mode": "background",
         "runtime_backend": args.runtime_backend,
+        "worker_placement": runtime.backend.placement,
         "backpressure": args.backpressure,
         "publish_policy": args.publish_policy or f"every:{args.publish_every}",
         "ingest_edges_per_s": mid_metrics["edges_per_s_ewma"],
@@ -398,6 +404,9 @@ def background_serve(args, tenant, engine, requests) -> tuple:
         "overflow_edges": tr["overflow_edges"],
         "spilled_batches": tr["spilled_batches"],
         "unaccounted_edges": tr["unaccounted_edges"],
+        "published_edges": cons["published_edges"],
+        "stream_total_edges": cons["stream_total_edges"],
+        "conservation_ok": cons["conservation_ok"],
         "checkpoints": tr["checkpoints"],
         "worker_state": tr["state"],
         **net_extras,
@@ -476,6 +485,7 @@ def sharded_main(args) -> None:
         "budget_kb": args.budget_kb,
         "ingest_mode": "sharded-background",
         "runtime_backend": args.runtime_backend,
+        "worker_placement": runtime.backend.placement,
         "n_shards": args.shards,
         "achieved_qps": round(report.achieved_qps, 1),
         "offered_qps": args.qps,
@@ -494,6 +504,7 @@ def sharded_main(args) -> None:
         "conservation_ok": cons["conservation_ok"],
         **net_extras,
         **{f"engine_{k}": v for k, v in engine.stats.items()},
+        "device": device_info(),
     }
     print(json.dumps(summary))
     if not cons["conservation_ok"]:
@@ -501,6 +512,7 @@ def sharded_main(args) -> None:
 
 
 def main() -> None:
+    init_compile_cache()
     args = parse_args()
     if args.no_donate:
         # must land before any SnapshotBuffer is built (tenant open);
@@ -529,6 +541,20 @@ def _run(args) -> None:
     if args.shards > 1:
         sharded_main(args)
         return
+    summary, *_ = serve_tenant(args)
+    print(json.dumps(summary))
+    # a background run that lost edges exits nonzero
+    if summary.get("unaccounted_edges"):
+        sys.exit(1)
+
+
+def serve_tenant(args) -> tuple:
+    """One tenant: warm ingest, then serve ``--n-requests`` mixed queries
+    while ingest continues (cooperatively or in the background runtime).
+
+    Returns ``(summary, tenant, engine, requests)`` so a caller can gate the
+    final snapshot against direct answers.
+    """
     registry = SketchRegistry(depth=args.depth, scale=args.scale,
                               partitioner=args.partitioner,
                               sketch_backend=args.sketch_backend or None)
@@ -579,10 +605,9 @@ def _run(args) -> None:
         "total_edges": final.n_edges,
         **extras,
         **{f"engine_{k}": v for k, v in engine.stats.items()},
+        "device": device_info(),
     }
-    print(json.dumps(summary))
-    if extras.get("unaccounted_edges"):
-        sys.exit(1)
+    return summary, tenant, engine, requests
 
 
 if __name__ == "__main__":

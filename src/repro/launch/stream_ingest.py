@@ -34,6 +34,7 @@ from repro.core.metrics import (
     exact_edge_frequencies,
     lookup_exact,
 )
+from repro.launch.host import device_info, init_compile_cache
 from repro.serving.registry import SKETCHES, build_sketch
 from repro.streams import make_stream, sample_stream
 
@@ -104,17 +105,12 @@ def runtime_main(args) -> None:
     if report["unaccounted_edges"]:
         raise SystemExit("edge conservation failed after drain")
 
-    sk, mod = tenant.snapshot.sketch, tenant.mod
-    src, dst, w = stream.all_edges_numpy()
-    fmap = exact_edge_frequencies(src, dst, w)
-    qs, qd, _ = sample_stream(stream, args.eval_queries, seed=99)
-    true = lookup_exact(fmap, qs, qd)
-    est = np.asarray(mod.edge_freq(sk, jnp.asarray(qs), jnp.asarray(qd)))
-    are = float(average_relative_error(jnp.asarray(est), jnp.asarray(true)))
+    are = evaluate_are(args, stream, tenant.snapshot.sketch, tenant.mod)
     print(json.dumps({"sketch": args.sketch, "dataset": args.dataset,
                       "budget_kb": args.budget_kb,
                       "runtime_backend": args.runtime_backend,
-                      "ARE": round(are, 4)}))
+                      "worker_placement": runtime.backend.placement,
+                      "ARE": round(are, 4), "device": device_info()}))
 
 
 def listen_main(args) -> None:
@@ -149,7 +145,7 @@ def listen_main(args) -> None:
         raise SystemExit(1)
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cit-HepPh")
     ap.add_argument("--sketch", default="kmatrix", choices=sorted(SKETCHES))
@@ -218,7 +214,7 @@ def main() -> None:
                          "including --listen worker hosts")
     ap.add_argument("--metrics-interval-s", type=float, default=1.0,
                     help="with --metrics-json: seconds between dumps")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     valid = ("inline", "thread", "process", "socket")
     if args.runtime_backend not in valid \
             and not args.runtime_backend.startswith("socket:"):
@@ -228,6 +224,12 @@ def main() -> None:
             and not args.listen:
         ap.error("--ingest-dedup requires a runtime backend "
                  "(--runtime-backend thread/process/socket)")
+    return args
+
+
+def main() -> None:
+    init_compile_cache()
+    args = parse_args()
     if args.no_donate:
         # must land before any SnapshotBuffer is built; the runtime
         # backends forward it to spawned/remote workers via the child spec
@@ -250,8 +252,11 @@ def main() -> None:
             dumper.stop()
 
 
-def inline_main(args) -> None:
-    """The original single-loop pipeline: jit ingest in this thread."""
+def build_inline(args):
+    """Stream -> bootstrap sample -> partition plan -> empty sketch.
+
+    Returns ``(stream, sketch, module)``.
+    """
     stream = make_stream(args.dataset, batch_size=args.batch_size,
                          seed=args.seed, scale=args.scale)
     print(f"stream: {stream.spec.name} nodes={stream.spec.n_nodes} "
@@ -267,7 +272,13 @@ def inline_main(args) -> None:
     print(f"init: {args.sketch} [{type(sk).__name__}] "
           f"counters={sk.num_counters} "
           f"({time.time()-t0:.2f}s init incl. sampling)")
+    return stream, sk, mod
 
+
+def run_inline_ingest(args, stream, sk, mod):
+    """Fold the whole stream into ``sk`` with one jitted step per batch,
+    checkpointing every ``--steps-per-ckpt`` batches when ``--ckpt-dir`` is
+    set.  Returns ``(sketch, jitted_step)``."""
     offset = 0
     if args.resume and args.ckpt_dir:
         try:
@@ -291,16 +302,28 @@ def inline_main(args) -> None:
     dt = time.time() - t0
     print(f"ingest: {n_edges} edges in {dt:.2f}s "
           f"({n_edges/max(dt,1e-9)/1e6:.2f} M edges/s)")
+    return sk, ingest
 
-    # evaluation against exact ground truth (paper Fig. 7 protocol)
+
+def evaluate_are(args, stream, sk, mod) -> float:
+    """Average relative error of ``--eval-queries`` sampled edge queries
+    against exact stream frequencies (paper Fig. 7 protocol)."""
     src, dst, w = stream.all_edges_numpy()
     fmap = exact_edge_frequencies(src, dst, w)
     qs, qd, _ = sample_stream(stream, args.eval_queries, seed=99)
     true = lookup_exact(fmap, qs, qd)
     est = np.asarray(mod.edge_freq(sk, jnp.asarray(qs), jnp.asarray(qd)))
-    are = float(average_relative_error(jnp.asarray(est), jnp.asarray(true)))
+    return float(average_relative_error(jnp.asarray(est), jnp.asarray(true)))
+
+
+def inline_main(args) -> None:
+    """The original single-loop pipeline: jit ingest in this thread."""
+    stream, sk, mod = build_inline(args)
+    sk, _ = run_inline_ingest(args, stream, sk, mod)
+    are = evaluate_are(args, stream, sk, mod)
     print(json.dumps({"sketch": args.sketch, "dataset": args.dataset,
-                      "budget_kb": args.budget_kb, "ARE": round(are, 4)}))
+                      "budget_kb": args.budget_kb, "ARE": round(are, 4),
+                      "device": device_info()}))
 
 
 if __name__ == "__main__":

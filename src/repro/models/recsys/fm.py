@@ -108,7 +108,7 @@ def retrieval_scores(cfg: FMConfig, params: dict, query_ids: jax.Array,
 
 
 def forward_with_kernel(cfg: FMConfig, params: dict, ids: jax.Array,
-                        *, interpret: bool = True) -> jax.Array:
+                        *, interpret: bool) -> jax.Array:
     """Same as forward() but the gather+reduce runs through the Pallas
     embedding_bag kernel (sum_v directly; squares via a second bag)."""
     from repro.kernels.embedding_bag import embedding_bag

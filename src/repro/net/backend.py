@@ -40,6 +40,7 @@ from repro.net.ingest_server import _selfhost_worker_main
 from repro.runtime.backend import (
     ExecutionBackend,
     build_child_spec,
+    check_child_placement,
     dispatch_parent_message,
 )
 from repro.runtime.metrics import WorkerMetrics
@@ -663,6 +664,12 @@ class SocketBackend(ExecutionBackend):
         self._next_addr = 0
         self.warm_shapes = warm_shapes
         self.child_env = dict(child_env or {})
+        # self-hosted workers are children of this process; dialed hosts
+        # were placed by whoever started them
+        self.placement = (
+            "worker hosts " + ",".join(f"{h}:{p}" for h, p in self.addresses)
+            if self.addresses else
+            check_child_placement(self.name, self.child_env))
         self._ctx = multiprocessing.get_context(mp_context)
         self.connect_timeout_s = connect_timeout_s
         self.frame_deadline_s = frame_deadline_s

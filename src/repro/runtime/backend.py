@@ -69,6 +69,34 @@ from repro.runtime.worker import (
 
 _BACKEND_NAMES = ("thread", "process", "socket")
 
+# child_env keys that place spawned workers explicitly: pinned to a platform
+# (JAX_PLATFORMS=cpu) or to a chip of their own
+_PLACEMENT_KEYS = ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS")
+
+
+def check_child_placement(backend: str, child_env: dict) -> str:
+    """One process per chip: refuse to spawn JAX workers beside a parent that
+    holds an accelerator, unless ``child_env`` places them explicitly.
+
+    Returns where the children's sketches live, for run summaries.
+    """
+    placed = {k: child_env[k] for k in _PLACEMENT_KEYS if k in child_env}
+    if placed:
+        return "child processes (" + ", ".join(
+            f"{k}={v}" for k, v in placed.items()) + ")"
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"the {backend} execution backend spawns worker processes that "
+            f"start their own JAX runtime, but this process already holds "
+            f"the {platform} device and a chip serves one process at a "
+            f"time; use the thread backend, or pass child_env="
+            f"{{'JAX_PLATFORMS': 'cpu'}} to keep the workers' sketches on "
+            f"the host CPU")
+    return "child processes (cpu)"
+
 
 class WorkerFailure(RuntimeError):
     """One or more ingest workers died; carries the original tracebacks.
@@ -104,6 +132,7 @@ class ExecutionBackend:
 
     name: str = ""
     remote: bool = False  # worker's sketch state lives outside this process
+    placement: str = "in-process"  # where the workers' sketches live
 
     def make_worker(self, tenant, queue: BoundedEdgeQueue, policy, *,
                     reservoir=None, checkpoint_dir: str | None = None,
@@ -913,6 +942,7 @@ class ProcessBackend(ExecutionBackend):
         # shared accelerator (JAX_PLATFORMS=cpu on a TPU host) or cap their
         # XLA host thread pools under core oversubscription
         self.child_env = dict(child_env or {})
+        self.placement = check_child_placement(self.name, self.child_env)
         # "delta" ships per-epoch sketch deltas (sparse-encoded); "full"
         # ships whole fronts — kept selectable for the A/B bench column
         self.publish_mode = publish_mode

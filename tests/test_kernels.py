@@ -29,6 +29,9 @@ from repro.kernels.ops import (
     (3, 1, 64, 128, 64),
     (2, 4, 16, 64, 32),
     (7, 2, 128, 256, 128),
+    (1, 2, 1024, 128, 128),  # out tile row-blocked (4 blocks of 256 rows)
+    (1, 1, 520, 128, 128),  # 2 blocks of 264 rows: row axis padded to 528
+    (1, 2, 600, 128, 128),  # 2 blocks of 304 rows: row axis padded to 608
 ])
 def test_matrix_ingest_matches_ref(d, p, w, c, tb):
     rng = np.random.default_rng(d * 100 + w)
@@ -212,6 +215,22 @@ def test_accel_matrix_sketch_equals_core():
         np.asarray(matrix_sketch.edge_freq(core_state, qs, qd)),
         np.asarray(accel_matrix_edge_freq(accel_state, qs, qd, block_q=128)),
     )
+
+
+def test_accel_matrix_sketch_equals_core_at_unaligned_width():
+    """A MatrixSketch whose width no row block divides (w = 520) still
+    counts every edge in every row, the last block's included."""
+    rng = np.random.default_rng(6)
+    sk = MatrixSketch.create(bytes_budget=4 * 520 * 520, depth=1, seed=3)
+    assert sk.w == 520
+    src = rng.integers(0, 5000, 600).astype(np.int32)
+    dst = rng.integers(0, 5000, 600).astype(np.int32)
+    w = rng.integers(1, 4, 600).astype(np.int32)
+    batch = EdgeBatch.from_numpy(src, dst, w)
+    core = np.asarray(matrix_sketch.ingest(sk, batch).table)
+    accel = np.asarray(accel_matrix_ingest(sk, batch, block_b=128).table)
+    assert core[:, 264:].sum() > 0  # the second row block is exercised
+    np.testing.assert_array_equal(core, accel)
 
 
 def test_kmatrix_accel_exact_counting():

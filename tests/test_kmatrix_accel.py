@@ -273,3 +273,19 @@ def test_registry_serves_accel_backend_exactly(monkeypatch):
             np.testing.assert_array_equal(g[1], w[1])
         else:
             assert g == w
+
+
+def test_class_wider_than_kernel_limit_refused_at_creation(monkeypatch):
+    """A width class the ingest kernel is not built for is refused when the
+    sketch is created (and on relayout), never routed to a scatter path."""
+    import importlib
+
+    # the package re-exports the kernel function under the module's name
+    matrix_ingest = importlib.import_module("repro.kernels.matrix_ingest")
+    flat = kma.to_flat_layout(_accel(seed=2))
+    widest = max(_accel(seed=2).class_widths)
+    monkeypatch.setattr(matrix_ingest, "MAX_WIDTH", widest // 2)
+    with pytest.raises(ValueError, match="exceeds the widest class"):
+        _accel(seed=2)
+    with pytest.raises(ValueError, match="exceeds the widest class"):
+        kma.to_class_layout(flat)

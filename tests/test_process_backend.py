@@ -298,3 +298,25 @@ def test_truncated_shard_manifest_fails_restore_loudly(tmp_path):
         json.dump({"n_shards": 2}, f)
     with pytest.raises(ValueError, match="missing required keys"):
         read_shard_manifest(ckpt)
+
+
+def test_spawning_backends_refuse_beside_an_accelerator(monkeypatch):
+    """One process per chip: when this process's JAX backend is a TPU, the
+    process and self-hosted socket backends refuse to spawn JAX children
+    unless ``child_env`` places them, and say where the sketches live."""
+    from repro.net.backend import SocketBackend
+    from repro.runtime.backend import ProcessBackend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="use the thread backend"):
+        ProcessBackend()
+    with pytest.raises(RuntimeError, match="use the thread backend"):
+        Runtime(backend="process")
+    with pytest.raises(RuntimeError, match="use the thread backend"):
+        SocketBackend()
+    assert (ProcessBackend(child_env={"JAX_PLATFORMS": "cpu"}).placement
+            == "child processes (JAX_PLATFORMS=cpu)")
+    # dialed worker hosts were placed by whoever started them
+    assert (SocketBackend(addresses=[("127.0.0.1", 7733)]).placement
+            == "worker hosts 127.0.0.1:7733")
+    assert Runtime(backend="thread").backend.placement == "in-process"

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro.core import kmatrix as km
 from repro.core import kmatrix_accel as kma
 from repro.core import matrix_sketch as ms
+from repro.obs.trace import get_trace_log
 
 
 def _bool_closure(adj: jax.Array, max_hops: int | None = None) -> jax.Array:
@@ -98,16 +99,14 @@ def build_closure(adj_layers: jax.Array, max_hops: int | None = None, *,
     the identical boolean fixpoint — squarings of a 0/1 float matrix are
     exact in f32 for w < 2^24 — and are parity-tested in tests/test_kernels.
     """
-    from repro.obs.profile import profile_call
-
-    if closure_backend(backend) == "jnp":
-        return profile_call("closure:jnp", _build_closure_jnp,
-                            adj_layers, max_hops)
-    w = adj_layers.shape[-1]
-    # pow-of-two tile <= 128 that covers small widths without overpadding
-    block = min(128, 1 << max(3, (max(w, 2) - 1).bit_length()))
-    return profile_call("closure:pallas", _build_closure_pallas, adj_layers,
-                        _closure_steps(w, max_hops), block)
+    with get_trace_log().span("kmatrix.engine.closure_build"):
+        if closure_backend(backend) == "jnp":
+            return _build_closure_jnp(adj_layers, max_hops)
+        w = adj_layers.shape[-1]
+        # pow-of-two tile <= 128 that covers small widths without overpadding
+        block = min(128, 1 << max(3, (max(w, 2) - 1).bit_length()))
+        return _build_closure_pallas(adj_layers, _closure_steps(w, max_hops),
+                                     block)
 
 
 def reachability_from_closure(closure: jax.Array, hi: jax.Array,

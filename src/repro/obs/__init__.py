@@ -2,9 +2,9 @@
 
 - ``hub``: mergeable counters/gauges/log-bucketed histograms + Prometheus
   text exposition
-- ``trace``: bounded span log with IDs propagated through queues, the
-  wire codec, and publish adoption
-- ``profile``: REPRO_PROFILE=1 timing hooks around kernel call sites
+- ``trace``: bounded event log with IDs propagated through queues, the
+  wire codec, and publish adoption; ``TraceLog.span`` host-phase spans on
+  the profiler's clock
 - ``dashboard``: live terminal poller (``python -m repro.obs.dashboard``)
 """
 from repro.obs.hub import (  # noqa: F401
@@ -13,10 +13,7 @@ from repro.obs.hub import (  # noqa: F401
     render_prometheus, quantile_from_state, merge_hist_states, hist_summary,
 )
 from repro.obs.trace import (  # noqa: F401
-    TraceLog, get_trace_log, reset_trace_log, new_trace_id,
-)
-from repro.obs.profile import (  # noqa: F401
-    profiling_enabled, profile_call, profile_span,
+    Span, TraceLog, get_trace_log, reset_trace_log, new_trace_id,
 )
 from repro.obs.dump import (  # noqa: F401
     MetricsJsonDumper, scrape_payload,

@@ -11,6 +11,15 @@ workers ``drain()`` their ring into the beats they already send; the
 parent ``absorb()``s, so one batch's enqueue -> dispatch -> publish ->
 adopt chain (or a query's accept -> plan -> execute -> reply chain) is
 reconstructable from a single JSONL dump regardless of transport.
+
+Those events carry wall-clock ``ts`` because they cross processes.  Host
+phases inside one process are timed with ``TraceLog.span`` instead: a
+context manager that enters ``jax.profiler.TraceAnnotation`` (so the span
+lands in any profiler trace, on the device trace's clock) and, on exit,
+appends ``Span(name, t0_ns, t1_ns, key, thread)`` on
+``time.perf_counter_ns`` to a second bounded ring.  ``key`` ties together
+the spans of one unit of work: a worker's dispatch sequence number, or the
+epoch a publish produces.  Span names are ``kmatrix.<layer>.<phase>``.
 """
 from __future__ import annotations
 
@@ -19,13 +28,50 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any
+from contextlib import nullcontext
+from typing import Any, NamedTuple
 
 from repro.obs.hub import metrics_disabled
 
-__all__ = ["new_trace_id", "TraceLog", "get_trace_log", "reset_trace_log"]
+__all__ = ["new_trace_id", "Span", "TraceLog", "get_trace_log",
+           "reset_trace_log"]
 
 DEFAULT_CAPACITY = 4096
+# Holds a 51 s window at about 2,000 spans/s, the rate of a saturated
+# ingest worker (about 7 spans per dispatch).
+SPAN_CAPACITY = 1 << 17
+
+_OFF = nullcontext()
+
+
+class Span(NamedTuple):
+    name: str
+    t0_ns: int  # time.perf_counter_ns at entry
+    t1_ns: int  # ... and at exit
+    key: Any  # what ties spans of one unit of work together, or None
+    thread: str
+
+
+class _SpanScope:
+    """One live span: the profiler annotation plus the ring record."""
+
+    __slots__ = ("_log", "_name", "_key", "_ann", "_t0")
+
+    def __init__(self, log: "TraceLog", name: str, key: Any) -> None:
+        self._log, self._name, self._key = log, name, key
+
+    def __enter__(self) -> "_SpanScope":
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._log.record_span(self._name, self._t0, t1, self._key)
 
 
 def new_trace_id() -> str:
@@ -39,6 +85,8 @@ class TraceLog:
         self._events: deque[dict] = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._emitted = 0
+        self._spans: deque[Span] = deque(maxlen=SPAN_CAPACITY)
+        self._spans_dropped = 0
 
     def emit(self, trace_id: str, span: str, event: str,
              **attrs: Any) -> None:
@@ -51,6 +99,35 @@ class TraceLog:
         with self._lock:
             self._events.append(rec)
             self._emitted += 1
+
+    def span(self, name: str, key: Any = None):
+        """Context manager timing one host phase (see the module doc).
+        A no-op while the metrics kill switch is set."""
+        if metrics_disabled():
+            return _OFF
+        return _SpanScope(self, name, key)
+
+    def record_span(self, name: str, t0_ns: int, t1_ns: int,
+                    key: Any = None, thread: str | None = None) -> None:
+        """Append one finished span; the oldest is dropped, and counted,
+        once the ring is full."""
+        if thread is None:
+            thread = threading.current_thread().name
+        rec = Span(name, t0_ns, t1_ns, key, thread)
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self._spans_dropped += 1
+            self._spans.append(rec)
+
+    def spans(self) -> list[Span]:
+        """Every span in the ring, in the order they ended."""
+        with self._lock:
+            return list(self._spans)
+
+    @property
+    def spans_dropped(self) -> int:
+        """Spans the full ring has dropped since it was made or cleared."""
+        return self._spans_dropped
 
     def absorb(self, events) -> None:
         """Fold a batch of remote events (from a drained child ring)."""
@@ -81,12 +158,17 @@ class TraceLog:
         return [e["event"] for e in self.events(trace_id)]
 
     def dump_jsonl(self, path: str) -> int:
-        """Append-write current events as JSONL; returns lines written."""
+        """Append-write current events, then spans, as JSONL; returns
+        lines written.  A span line has ``t0_ns``/``t1_ns`` and no
+        ``trace``."""
         evs = self.events()
+        spans = self.spans()
         with open(path, "a") as fh:
             for rec in evs:
                 fh.write(json.dumps(rec, default=str) + "\n")
-        return len(evs)
+            for sp in spans:
+                fh.write(json.dumps(sp._asdict(), default=str) + "\n")
+        return len(evs) + len(spans)
 
     @property
     def emitted(self) -> int:
@@ -95,6 +177,8 @@ class TraceLog:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+            self._spans.clear()
+            self._spans_dropped = 0
 
 
 _GLOBAL: TraceLog | None = None

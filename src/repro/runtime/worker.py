@@ -36,7 +36,6 @@ import numpy as np
 
 from repro.checkpoint import store
 from repro.core.types import EdgeBatch
-from repro.obs.profile import profile_span
 from repro.obs.trace import get_trace_log
 from repro.runtime.metrics import WorkerMetrics
 from repro.runtime.policies import PublishPolicy
@@ -160,6 +159,8 @@ class IngestWorker(threading.Thread):
         self._stage: list = [None, None]
         self._stage_fence: list = [None, None]
         self._stage_idx = 0
+        # key of the ``kmatrix.worker.*`` spans of the next dispatch
+        self._dispatch_seq = 0
         self.metrics = WorkerMetrics()
         self.metrics.bind_hub(tenant.key.tenant_id)
         self._trace = get_trace_log()
@@ -202,7 +203,9 @@ class IngestWorker(threading.Thread):
                 if item is not None:
                     self._held = None  # byte-cap holdover leads this group
                 else:
-                    item = self.queue.get(timeout=self.poll_s)
+                    with self._trace.span("kmatrix.worker.queue_get",
+                                          key=self._dispatch_seq):
+                        item = self.queue.get(timeout=self.poll_s)
                 now = time.monotonic()
                 if item is None:
                     if self._stop_event.is_set():
@@ -276,13 +279,17 @@ class IngestWorker(threading.Thread):
             self._pending_traces.append(item.trace_id)
 
     def _ingest(self, item: QueueItem, now: float) -> None:
-        batch = EdgeBatch.from_numpy(item.src, item.dst, item.weight)
+        key = self._dispatch_seq
+        with self._trace.span("kmatrix.worker.stage", key=key):
+            batch = EdgeBatch.from_numpy(item.src, item.dst, item.weight)
         self._note_dispatch(item)
         with self._state_lock:
-            with profile_span("ingest"):
+            with self._trace.span("kmatrix.worker.dispatch", key=key):
                 self.tenant.buffer.ingest(batch)
             if self.reservoir is not None:
-                self.reservoir.offer_batch(item.src, item.dst, item.weight)
+                with self._trace.span("kmatrix.worker.reservoir", key=key):
+                    self.reservoir.offer_batch(item.src, item.dst,
+                                               item.weight)
             if item.offset >= 0:
                 # externally submitted batches carry offset -1: they are not
                 # part of the seekable stream, so they must not move the
@@ -291,6 +298,7 @@ class IngestWorker(threading.Thread):
                 self.tenant.offset = item.offset + 1
         self.metrics.note_ingest(item.n_edges, now)
         self._batches_since_checkpoint += 1
+        self._dispatch_seq += 1
 
     def _claim_stage(self, bucket: int):
         """Borrow a host staging column set of ≥ ``bucket`` rows (ping-pong).
@@ -307,7 +315,9 @@ class IngestWorker(threading.Thread):
         self._stage_idx ^= 1
         fence = self._stage_fence[slot]
         if fence is not None:
-            jax.block_until_ready(fence)
+            with self._trace.span("kmatrix.worker.stage_wait",
+                                  key=self._dispatch_seq):
+                jax.block_until_ready(fence)
             self._stage_fence[slot] = None
         bufs = self._stage[slot]
         if bufs is None or bufs[0].shape[0] < bucket:
@@ -324,6 +334,11 @@ class IngestWorker(threading.Thread):
             # no completion fence available: never reuse this staging set
             self._stage[slot] = None
 
+    def dispatch_granule(self) -> int:
+        """Row granule a coalesced dispatch is padded to: every dispatched
+        row count is a multiple of it, so the compiled shapes stay few."""
+        return max(256, self.coalesce_target // 4)
+
     def _ingest_coalesced(self, items: list[QueueItem], now: float) -> None:
         """Fold several queued items into ONE buffer ingest dispatch.
 
@@ -333,62 +348,68 @@ class IngestWorker(threading.Thread):
         so the offset cursor can jump straight to the newest seekable batch
         (FIFO ⇒ the last item is the newest) without ever describing a
         state the counters do not hold.  Padded to a coarse ladder
-        (``coalesce_target/4`` granule) so coalesced shapes stay few.
+        (``dispatch_granule``) so coalesced shapes stay few.
 
         With ``dedup`` on, the group is pre-aggregated on (src, dst) first
         (bit-exact — see ``preaggregate_edges``) and the pending ledger
         takes the host-side raw weight>0 count instead of the device count.
         """
-        n_raw = sum(it.src.shape[0] for it in items)
+        key = self._dispatch_seq
+        span = self._trace.span
         count = None
         if self.dedup:
-            if len(items) == 1:
-                rs, rd, rw = items[0].src, items[0].dst, items[0].weight
-            else:
-                rs = np.concatenate([np.asarray(it.src) for it in items])
-                rd = np.concatenate([np.asarray(it.dst) for it in items])
-                rw = np.concatenate([np.asarray(it.weight) for it in items])
-            raw_live = int(np.count_nonzero(np.asarray(rw)))
-            usrc, udst, uw = preaggregate_edges(rs, rd, rw)
+            with span("kmatrix.worker.dedup", key=key):
+                if len(items) == 1:
+                    rs, rd, rw = items[0].src, items[0].dst, items[0].weight
+                else:
+                    rs = np.concatenate([np.asarray(it.src) for it in items])
+                    rd = np.concatenate([np.asarray(it.dst) for it in items])
+                    rw = np.concatenate([np.asarray(it.weight)
+                                         for it in items])
+                raw_live = int(np.count_nonzero(np.asarray(rw)))
+                usrc, udst, uw = preaggregate_edges(rs, rd, rw)
             n = usrc.shape[0]
             count = sum(it.n_edges for it in items)
         else:
-            n = n_raw
-        granule = max(256, self.coalesce_target // 4)
+            n = sum(it.src.shape[0] for it in items)
+        granule = self.dispatch_granule()
         bucket = max(granule, -(-n // granule) * granule)
         # pre-sized int32 staging per column, filled by slicing: the slice
         # assignment does the cast AND the copy, and the zero tail IS the
         # weight-0 padding pad_to produced
         slot, (src, dst, weight) = self._claim_stage(bucket)
-        if self.dedup:
-            src[:n] = usrc
-            dst[:n] = udst
-            weight[:n] = uw
-        else:
-            pos = 0
-            for it in items:
-                end = pos + it.src.shape[0]
-                src[pos:end] = it.src
-                dst[pos:end] = it.dst
-                weight[pos:end] = it.weight
-                pos = end
-        src[n:bucket] = 0
-        dst[n:bucket] = 0
-        weight[n:bucket] = 0
-        batch = EdgeBatch.from_numpy(src[:bucket], dst[:bucket],
-                                     weight[:bucket])
+        with span("kmatrix.worker.stage", key=key):
+            if self.dedup:
+                src[:n] = usrc
+                dst[:n] = udst
+                weight[:n] = uw
+            else:
+                pos = 0
+                for it in items:
+                    end = pos + it.src.shape[0]
+                    src[pos:end] = it.src
+                    dst[pos:end] = it.dst
+                    weight[pos:end] = it.weight
+                    pos = end
+            src[n:bucket] = 0
+            dst[n:bucket] = 0
+            weight[n:bucket] = 0
+            batch = EdgeBatch.from_numpy(src[:bucket], dst[:bucket],
+                                         weight[:bucket])
         for it in items:
             self._note_dispatch(it)
         with self._state_lock:
-            with profile_span("ingest"):
+            with span("kmatrix.worker.dispatch", key=key):
                 if count is None:
                     self.tenant.buffer.ingest(batch)
                 else:
                     self.tenant.buffer.ingest(batch, count=count)
-            self._fence_stage(slot)
+                self._fence_stage(slot)
             if self.reservoir is not None:
-                for it in items:
-                    self.reservoir.offer_batch(it.src, it.dst, it.weight)
+                with span("kmatrix.worker.reservoir", key=key):
+                    for it in items:
+                        self.reservoir.offer_batch(it.src, it.dst,
+                                                   it.weight)
             offsets = [it.offset for it in items if it.offset >= 0]
             if offsets:
                 self._ingested_offset = offsets[-1]
@@ -398,6 +419,7 @@ class IngestWorker(threading.Thread):
         if self.dedup:
             self.metrics.note_dedup(raw_live, n)
         self._batches_since_checkpoint += len(items)
+        self._dispatch_seq += 1
 
     def _should_publish(self, now: float) -> bool:
         return self.policy.should_publish(
@@ -406,7 +428,9 @@ class IngestWorker(threading.Thread):
 
     def _publish(self):
         t0 = time.monotonic()
-        snap = self.tenant.publish()
+        with self._trace.span("kmatrix.worker.publish",
+                              key=self.tenant.epoch + 1):
+            snap = self.tenant.publish()
         now = time.monotonic()
         self.metrics.note_publish(now - t0, now)
         self.policy.note_published(now)

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import EdgeBatch
+from repro.obs.trace import get_trace_log
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,7 +294,9 @@ class SnapshotBuffer:
         edge count is fetched to stamp the snapshot).
         """
         with self._lock:
-            pending = int(jax.device_get(self._pending))
+            with get_trace_log().span("kmatrix.snapshot.publish_sync",
+                                      key=self._front.epoch + 1):
+                pending = int(jax.device_get(self._pending))
             if self.capture_publish_delta:
                 # the outgoing delta is exactly what this publish folds in;
                 # the reference stays valid (JAX arrays are immutable) —

@@ -242,3 +242,27 @@ def test_donation_defaults_and_kill_switch_env(monkeypatch):
     assert SnapshotBuffer(sk, countmin, tenant_id="t").donate
     monkeypatch.setenv("REPRO_DONATE", "0")
     assert not SnapshotBuffer(sk, countmin, tenant_id="t").donate
+
+
+@pytest.mark.parametrize("target", [64, 1024, 4096, 8192, 65536])
+def test_dispatch_granule_is_the_padding_rule(target, monkeypatch):
+    """``dispatch_granule`` is the rule ``max(256, coalesce_target // 4)``
+    (which the benchmark's warm-up copies), and the coalesced dispatch pads
+    its rows to a multiple of it."""
+    from repro.runtime import BoundedEdgeQueue, make_policy
+
+    t = _registry().open("cit-HepPh", "kmatrix", 64, seed=0)
+    worker = IngestWorker(t, BoundedEdgeQueue(4), make_policy("every:1"),
+                          coalesce_target=target)
+    granule = max(256, target // 4)
+    assert worker.dispatch_granule() == granule
+    rows = []
+    monkeypatch.setattr(t.buffer, "ingest",
+                        lambda batch, count=None:
+                        rows.append(batch.src.shape[0]))
+    rng = np.random.default_rng(target)
+    for n in (1, granule, granule + 1, 3 * granule - 1):
+        src, dst, w = _random_edges(rng, n)
+        worker._ingest_coalesced(
+            [QueueItem.from_arrays(-1, src, dst, w)], 0.0)
+        assert rows[-1] == max(granule, -(-n // granule) * granule)

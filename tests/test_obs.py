@@ -439,25 +439,149 @@ def test_metrics_json_dumper_and_dashboard_once(tmp_path):
                       "--once"]) == 1
 
 
-def test_profile_hooks_record_when_enabled(monkeypatch):
-    from repro.obs import profile as prof
+# ------------------------------------------------------------ host spans
 
-    monkeypatch.setenv("REPRO_PROFILE", "1")
-    prof._reset_for_tests()
-    out = prof.profile_call("unit:test", lambda a, b: a + b, 2, 3)
-    assert out == 5
-    with prof.profile_span("unit:span"):
+
+def test_span_ring_keeps_end_order_bounds_and_counts_drops(monkeypatch):
+    from repro.obs import trace
+
+    monkeypatch.setattr(trace, "SPAN_CAPACITY", 8)
+    log = trace.TraceLog()
+    for i in range(11):
+        with log.span("kmatrix.test.phase", key=i):
+            pass
+    spans = log.spans()
+    assert [s.key for s in spans] == list(range(3, 11))  # oldest dropped
+    assert log.spans_dropped == 3
+    assert all(s.t0_ns <= s.t1_ns for s in spans)
+    assert [s.t1_ns for s in spans] == sorted(s.t1_ns for s in spans)
+    log.clear()
+    assert log.spans() == [] and log.spans_dropped == 0
+
+
+def test_span_honours_the_metrics_kill_switch():
+    log = get_trace_log()
+    set_disabled(True)
+    ran = []
+    with log.span("kmatrix.test.off", key=1):
+        ran.append(1)
+    assert ran == [1] and log.spans() == []
+    set_disabled(False)
+    with log.span("kmatrix.test.on", key=2):
         pass
-    hists = {tuple(sorted(l.items())): h for n, l, h
-             in get_hub().state()["hists"] if n == "repro_profile_seconds"}
-    assert hists[(("site", "unit:test"),)]["count"] == 1
-    assert hists[(("site", "unit:span"),)]["count"] == 1
-    monkeypatch.delenv("REPRO_PROFILE")
-    prof._reset_for_tests()
-    prof.profile_call("unit:off", lambda: None)
-    assert not any(tuple(sorted(l.items())) == (("site", "unit:off"),)
-                   for n, l, _ in get_hub().state()["hists"]
-                   if n == "repro_profile_seconds")
+    assert [(s.name, s.key) for s in log.spans()] == [("kmatrix.test.on", 2)]
+
+
+def test_nested_spans_share_a_key_and_nest_in_time():
+    log = get_trace_log()
+    with log.span("kmatrix.test.outer", key=7):
+        time.sleep(0.001)
+        with log.span("kmatrix.test.inner", key=7):
+            time.sleep(0.001)
+    with log.span("kmatrix.test.bare"):
+        pass
+    inner, outer, bare = log.spans()  # appended as each span ends
+    assert (inner.name, outer.name) == ("kmatrix.test.inner",
+                                        "kmatrix.test.outer")
+    assert inner.key == outer.key == 7 and bare.key is None
+    assert outer.t0_ns <= inner.t0_ns <= inner.t1_ns <= outer.t1_ns
+    assert inner.t1_ns - inner.t0_ns >= 1_000_000
+    assert outer.thread == inner.thread == threading.current_thread().name
+
+
+def test_span_survives_an_exception_in_its_body():
+    log = get_trace_log()
+    with pytest.raises(ValueError):
+        with log.span("kmatrix.test.raises", key=3):
+            raise ValueError("boom")
+    assert [s.name for s in log.spans()] == ["kmatrix.test.raises"]
+
+
+def test_span_log_dumps_events_and_spans_as_jsonl(tmp_path):
+    log = get_trace_log()
+    log.emit("t1", "ingest", "enqueue", offset=0)
+    with log.span("kmatrix.test.phase", key=5):
+        pass
+    path = tmp_path / "spans.jsonl"
+    assert log.dump_jsonl(str(path)) == 2
+    event, span = [json.loads(line)
+                   for line in path.read_text().splitlines()]
+    assert event["trace"] == "t1" and "t0_ns" not in event
+    assert span["name"] == "kmatrix.test.phase" and span["key"] == 5
+    assert span["t1_ns"] >= span["t0_ns"] and "trace" not in span
+    assert span["thread"] == threading.current_thread().name
+
+
+def test_span_lands_in_a_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with get_trace_log().span("kmatrix.test.profiled", key=1):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = ProfileData.from_file(files[0])
+    names = {e.name for plane in data.planes for line in plane.lines
+             for e in line.events}
+    assert "kmatrix.test.profiled" in names
+    assert [s.name for s in get_trace_log().spans()] == [
+        "kmatrix.test.profiled"]
+
+
+@pytest.mark.parametrize("dedup", [True, False])
+def test_thread_worker_spans_key_each_dispatch_and_each_epoch(dedup):
+    """One thread-runtime ingest: every dispatch's spans share its sequence
+    number and run in order, and every publish's span and its device-sync
+    child carry the epoch it produced."""
+    from repro.runtime import Runtime
+
+    reg = _registry()
+    t = reg.open("cit-HepPh", "kmatrix", 64, seed=0)
+    rt = Runtime(publish_policy="every:2", dedup=dedup, backend="thread")
+    rt.attach(t)
+    rt.start(pumps=False)
+    rt.wait_ready()
+    rt.start_pumps()
+    rt.join_pumps()
+    assert rt.stop(drain=True)[t.key.tenant_id]["unaccounted_edges"] == 0
+
+    by: dict = {}
+    for s in get_trace_log().spans():
+        by.setdefault(s.name, []).append(s)
+    worker = {s.thread for name, ss in by.items()
+              if name.startswith("kmatrix.worker.") for s in ss}
+    assert len(worker) == 1
+    keys = sorted(s.key for s in by["kmatrix.worker.dispatch"])
+    assert keys == list(range(len(keys))) and len(keys) >= 4
+    phases = ["stage", "dispatch", "reservoir"]
+    if dedup:
+        phases.insert(0, "dedup")
+    else:
+        assert "kmatrix.worker.dedup" not in by
+    one = {p: {s.key: s for s in by[f"kmatrix.worker.{p}"]} for p in phases}
+    for p in phases:
+        assert sorted(one[p]) == keys, p  # once per dispatch
+    gets = by["kmatrix.worker.queue_get"]
+    assert set(keys) <= {s.key for s in gets} <= set(keys) | {len(keys)}
+    for k in keys:
+        got = max(s.t1_ns for s in gets if s.key == k)
+        ends = [got] + [x for p in phases
+                        for x in (one[p][k].t0_ns, one[p][k].t1_ns)]
+        assert ends == sorted(ends), k  # queue_get, then each phase in turn
+
+    pubs = {s.key: s for s in by["kmatrix.worker.publish"]}
+    assert sorted(pubs) == list(range(1, t.epoch + 1))
+    syncs = {s.key: s for s in by["kmatrix.snapshot.publish_sync"]
+             if s.thread in worker}
+    assert sorted(syncs) == sorted(pubs)
+    for epoch, sync in syncs.items():
+        assert pubs[epoch].t0_ns <= sync.t0_ns <= sync.t1_ns \
+            <= pubs[epoch].t1_ns
 
 
 def test_loadgen_reports_carry_merged_histogram_summary(rng):

@@ -115,6 +115,9 @@ def donation_enabled() -> bool:
 # The pending scalar is a fresh 4/8-byte output per dispatch, and holding
 # its reference gives callers a completion fence: it becomes ready exactly
 # when that dispatch finished executing (SnapshotBuffer.dispatch_token).
+# ``publish`` reads it back only when some ingest since the previous
+# publish came without a host count; a counted-only epoch is stamped from
+# the buffer's host shadow of the same total, so it waits on no dispatch.
 _KernelKit = namedtuple(
     "_KernelKit", ["ingest", "ingest_counted", "publish", "publish_keep"])
 _KERNELS: dict = {}
@@ -206,6 +209,9 @@ class SnapshotBuffer:
         # into the ingest kernel so each batch is ONE dispatch
         self._pending = jnp.zeros((), jnp.int64 if jax.config.x64_enabled  # guarded-by: _lock
                                   else jnp.int32)
+        # host shadow of _pending: exact while every ingest since the last
+        # publish carried a host count, None once one did not
+        self._host_pending: int | None = 0  # guarded-by: _lock
         self._kernels = _shared_kernels(mod, self.donate)
         # Delta-publication support (runtime/backend.py): with the flag on,
         # each publish() stashes the pre-merge delta pytree (an immutable
@@ -262,15 +268,21 @@ class SnapshotBuffer:
         rows on the host (runtime/worker.py dedup path), the dispatched
         rows no longer map 1:1 to stream updates, so the device-side
         weight>0 count would under-report; the host count keeps the pending
-        ledger bit-identical to the un-deduped replay.
+        ledger bit-identical to the un-deduped replay.  While every ingest
+        since the last publish carries one, ``publish`` stamps the epoch
+        from their sum on the host instead of reading the device back.
         """
         with self._lock:
             if count is None:
                 self._delta, self._pending = self._kernels.ingest(  # donates: 0
                     self._delta, batch, self._pending)
+                self._host_pending = None
             else:
+                count = int(count)
                 self._delta, self._pending = self._kernels.ingest_counted(  # donates: 0
-                    self._delta, batch, int(count), self._pending)
+                    self._delta, batch, count, self._pending)
+                if self._host_pending is not None:
+                    self._host_pending += count
 
     def dispatch_token(self):
         """Opaque completion fence for everything dispatched so far.
@@ -290,13 +302,24 @@ class SnapshotBuffer:
     def publish(self) -> Snapshot:
         """Fold the delta into the front buffer and stamp a new epoch.
 
-        This is the only host sync point in the ingest path (the pending
-        edge count is fetched to stamp the snapshot).
+        The epoch's edge count comes from the host shadow when every
+        ingest since the previous publish carried a host count (the dedup
+        path): then nothing here waits on the device, and the returned
+        front is a future of the merge, as it always was.  After an
+        uncounted ingest the pending count is fetched from the device —
+        the ingest path's only host sync point, which waits for every
+        dispatch in flight.
         """
         with self._lock:
-            with get_trace_log().span("kmatrix.snapshot.publish_sync",
-                                      key=self._front.epoch + 1):
-                pending = int(jax.device_get(self._pending))
+            epoch = self._front.epoch + 1
+            if self._host_pending is None:
+                with get_trace_log().span("kmatrix.snapshot.publish_sync",
+                                          key=epoch):
+                    pending = int(jax.device_get(self._pending))
+            else:
+                with get_trace_log().span(
+                        "kmatrix.snapshot.publish_host_count", key=epoch):
+                    pending = self._host_pending
             if self.capture_publish_delta:
                 # the outgoing delta is exactly what this publish folds in;
                 # the reference stays valid (JAX arrays are immutable) —
@@ -310,13 +333,14 @@ class SnapshotBuffer:
             merged, delta = kern(self._front.sketch, self._delta)  # donates: 1
             self._front = Snapshot(
                 self._tenant_id,
-                self._front.epoch + 1,
+                epoch,
                 merged,
                 self._kind,
                 self._front.n_edges + pending,
             )
             self._delta = delta
             self._pending = jnp.zeros_like(self._pending)
+            self._host_pending = 0
             return self._front
 
     def adopt_published(self, sketch: Any, epoch: int, n_edges: int, *,
@@ -404,4 +428,7 @@ class SnapshotBuffer:
             self._delta = restore(state["delta"])
             self._pending = jnp.array(state["pending"],
                                       dtype=self._pending.dtype, copy=True)
+            # a restore is off the hot path: read the restored count once
+            # so the next counted-only epoch needs no device read either
+            self._host_pending = int(jax.device_get(self._pending))
             return self._front

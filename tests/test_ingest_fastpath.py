@@ -186,6 +186,103 @@ def test_donated_buffer_capture_publish_delta_stays_readable():
         assert isinstance(total, int)  # readable, not deleted
 
 
+# ----------------------------------------------- publish's pending count
+def _live(batch):
+    return int(np.count_nonzero(batch[2] > 0))
+
+
+def _publish_counting_device_gets(buf, monkeypatch):
+    """``buf.publish()`` with ``jax.device_get`` counted while it runs."""
+    calls = []
+    real = jax.device_get
+
+    def counting(x):
+        calls.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", counting)
+    try:
+        snap = buf.publish()
+    finally:
+        monkeypatch.setattr(jax, "device_get", real)
+    return snap, len(calls)
+
+
+def _counted_only(buf, monkeypatch):
+    batches = _batches(11)
+    for b in batches:
+        buf.ingest(EdgeBatch.from_numpy(*b), count=_live(b))
+    snap, gets = _publish_counting_device_gets(buf, monkeypatch)
+    assert gets == 0  # stamped from the host's own count
+    return snap, sum(_live(b) for b in batches)
+
+
+def _one_uncounted(buf, monkeypatch):
+    batches = _batches(12)
+    for i, b in enumerate(batches):
+        if i == 3:
+            buf.ingest(EdgeBatch.from_numpy(*b))  # only the device counts it
+        else:
+            buf.ingest(EdgeBatch.from_numpy(*b), count=_live(b))
+    snap, gets = _publish_counting_device_gets(buf, monkeypatch)
+    assert gets == 1  # falls back to the device count
+    # and the next epoch, counted only, is back on the host count
+    more = _batches(13, k=2)
+    for b in more:
+        buf.ingest(EdgeBatch.from_numpy(*b), count=_live(b))
+    snap2, gets = _publish_counting_device_gets(buf, monkeypatch)
+    assert gets == 0
+    assert snap2.n_edges - snap.n_edges == sum(_live(b) for b in more)
+    return snap, sum(_live(b) for b in batches)
+
+
+def _after_load_state(buf, monkeypatch):
+    src_buf = SnapshotBuffer(buf.snapshot.sketch, countmin, tenant_id="s",
+                             donate=buf.donate)
+    first, rest = _batches(14, k=3), _batches(15, k=3)
+    _feed(src_buf, first[:1])  # uncounted: the checkpoint's pending
+    src_buf.publish()
+    _feed(src_buf, first[1:])
+    buf.load_state(src_buf.state())
+    for b in rest:
+        buf.ingest(EdgeBatch.from_numpy(*b), count=_live(b))
+    snap, gets = _publish_counting_device_gets(buf, monkeypatch)
+    assert gets == 0  # the restore read the pending count already
+    return snap, sum(_live(b) for b in first + rest)
+
+
+def _dedup_against_plain_runtime(buf, monkeypatch):
+    from repro.obs.trace import reset_trace_log
+
+    runs = {}
+    for dedup in (False, True):
+        log = reset_trace_log()
+        snap, _ = _run_runtime(dedup=dedup)
+        runs[dedup] = snap, {s.name for s in log.spans()
+                             if s.name.startswith("kmatrix.snapshot.")}
+    reset_trace_log()
+    (base, base_paths), (fast, fast_paths) = runs[False], runs[True]
+    assert base_paths == {"kmatrix.snapshot.publish_sync"}
+    assert fast_paths == {"kmatrix.snapshot.publish_host_count"}
+    assert layout_counters_equal(fast.sketch, base.sketch)
+    assert fast.epoch >= 1
+    return fast, base.n_edges
+
+
+@pytest.mark.parametrize("case", [_counted_only, _one_uncounted,
+                                  _after_load_state,
+                                  _dedup_against_plain_runtime],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_publish_stamps_exact_n_edges_from_the_host_count(case, monkeypatch):
+    """``publish`` stamps ``n_edges`` from the host's own count while every
+    ingest since the previous publish carried one, and from the device
+    count otherwise; either way the count is exact."""
+    sk = countmin.CountMin.create(bytes_budget=4096, depth=3, seed=9)
+    buf = SnapshotBuffer(sk, countmin, tenant_id="t")
+    snap, want = case(buf, monkeypatch)
+    assert snap.n_edges == want
+
+
 # ------------------------------------------------- runtime fast-path A/B
 def _run_runtime(dataset="email-EuAll", *, dedup, backend="thread",
                  max_batches=12, **rt_kw):

@@ -536,8 +536,10 @@ def test_span_lands_in_a_profiler_trace(tmp_path):
 @pytest.mark.parametrize("dedup", [True, False])
 def test_thread_worker_spans_key_each_dispatch_and_each_epoch(dedup):
     """One thread-runtime ingest: every dispatch's spans share its sequence
-    number and run in order, and every publish's span and its device-sync
-    child carry the epoch it produced."""
+    number and run in order, and every publish's span and its child carry
+    the epoch it produced.  The child is the device sync without dedup,
+    and the host-count read with it (every dedup dispatch carries its
+    count, so no publish waits on the device)."""
     from repro.runtime import Runtime
 
     reg = _registry()
@@ -576,7 +578,12 @@ def test_thread_worker_spans_key_each_dispatch_and_each_epoch(dedup):
 
     pubs = {s.key: s for s in by["kmatrix.worker.publish"]}
     assert sorted(pubs) == list(range(1, t.epoch + 1))
-    syncs = {s.key: s for s in by["kmatrix.snapshot.publish_sync"]
+    child, other = "publish_sync", "publish_host_count"
+    if dedup:
+        child, other = other, child
+    assert not [s for s in by.get(f"kmatrix.snapshot.{other}", [])
+                if s.thread in worker]
+    syncs = {s.key: s for s in by[f"kmatrix.snapshot.{child}"]
              if s.thread in worker}
     assert sorted(syncs) == sorted(pubs)
     for epoch, sync in syncs.items():

@@ -29,6 +29,7 @@ from repro.core import (
 )
 from repro.core import sketch_backend as resolve_sketch_backend
 from repro.core import countmin, gsketch, kmatrix, kmatrix_accel, matrix_sketch
+from repro.obs.trace import get_trace_log
 from repro.serving.snapshot import Snapshot, SnapshotBuffer
 from repro.streams import make_stream, sample_stream
 
@@ -205,21 +206,14 @@ class SketchRegistry:
         with self._lock:
             if key in self._tenants:
                 return self._tenants[key]
-        stream = make_stream(dataset, batch_size=self.batch_size, seed=seed,
-                             scale=self.scale)
-        # Paper §V-A: a reservoir sample of the stream bootstraps the
-        # partitioner before any counter is allocated.
-        n_sample = max(int(self.sample_size * self.scale), 1000)
-        ssrc, sdst, sw = sample_stream(stream, n_sample, seed=seed + 1)
-        stats = vertex_stats_from_sample(ssrc, sdst, sw)
-        sketch, mod = build_sketch(kind, budget_kb * 1024, stats, self.depth,
-                                   seed, self.partitioner,
-                                   backend=self.sketch_backend)
+        stream, sketch, mod = self._bootstrap(key)
         with self._lock:
             if key in self._tenants:  # lost the build race; first one wins
                 return self._tenants[key]
-            buffer = SnapshotBuffer(sketch, mod, tenant_id=key.tenant_id,
-                                    kind=kind)
+            with get_trace_log().span("kmatrix.registry.alloc",
+                                      key=key.tenant_id):
+                buffer = SnapshotBuffer(sketch, mod, tenant_id=key.tenant_id,
+                                        kind=kind)
             tenant = Tenant(key, stream, buffer, mod)
             tenant.origin = TenantOrigin(self.config(), dataset, kind,
                                          budget_kb, seed)
@@ -247,32 +241,51 @@ class SketchRegistry:
         with self._lock:
             if skey in self._sharded:
                 return self._sharded[skey]
-        stream = make_stream(dataset, batch_size=self.batch_size, seed=seed,
-                             scale=self.scale)
-        n_sample = max(int(self.sample_size * self.scale), 1000)
-        ssrc, sdst, sw = sample_stream(stream, n_sample, seed=seed + 1)
-        stats = vertex_stats_from_sample(ssrc, sdst, sw)
-        sketch, mod = build_sketch(kind, budget_kb * 1024, stats, self.depth,
-                                   seed, self.partitioner,
-                                   backend=self.sketch_backend)
+        stream, sketch, mod = self._bootstrap(key)
         plan = ShardPlan(n_shards, seed=shard_seed)
         shards = []
-        for s in range(n_shards):
-            shard_key = ShardKey(key, s, n_shards)
-            view = ShardStreamView(stream, plan, s)
-            buffer = SnapshotBuffer(mod.empty_like(sketch), mod,
-                                    tenant_id=shard_key.tenant_id, kind=kind)
-            shard = Tenant(shard_key, view, buffer, mod)
-            shard.origin = TenantOrigin(self.config(), dataset, kind,
-                                        budget_kb, seed, n_shards=n_shards,
-                                        shard_seed=shard_seed, shard_index=s)
-            shards.append(shard)
+        with get_trace_log().span("kmatrix.registry.alloc",
+                                  key=key.tenant_id):
+            for s in range(n_shards):
+                shard_key = ShardKey(key, s, n_shards)
+                view = ShardStreamView(stream, plan, s)
+                buffer = SnapshotBuffer(mod.empty_like(sketch), mod,
+                                        tenant_id=shard_key.tenant_id,
+                                        kind=kind)
+                shard = Tenant(shard_key, view, buffer, mod)
+                shard.origin = TenantOrigin(
+                    self.config(), dataset, kind, budget_kb, seed,
+                    n_shards=n_shards, shard_seed=shard_seed, shard_index=s)
+                shards.append(shard)
         tenant = ShardedTenant(key, plan, shards, mod)
         with self._lock:
             if skey in self._sharded:  # lost the build race; first one wins
                 return self._sharded[skey]
             self._sharded[skey] = tenant
             return tenant
+
+    def _bootstrap(self, key: TenantKey):
+        """(stream, sketch, module) of a key: the seekable stream, its
+        bootstrap sample, and the sketch built from the sample's partition
+        plan, with zeroed counters.  The set-up phases are spans keyed by
+        the tenant id (``kmatrix.registry.sample``, ``.plan``; the caller's
+        ``.alloc`` covers the snapshot buffers)."""
+        span = get_trace_log().span
+        stream = make_stream(key.dataset, batch_size=self.batch_size,
+                             seed=key.seed, scale=self.scale)
+        # Paper §V-A: a reservoir sample of the stream bootstraps the
+        # partitioner before any counter is allocated.
+        n_sample = max(int(self.sample_size * self.scale), 1000)
+        with span("kmatrix.registry.sample", key=key.tenant_id):
+            sample = sample_stream(stream, n_sample, seed=key.seed + 1)
+        # build_sketch plans and zeroes the sketch in one call; the zeroing
+        # is dispatched, not waited for, so this span is the plan's
+        with span("kmatrix.registry.plan", key=key.tenant_id):
+            stats = vertex_stats_from_sample(*sample)
+            sketch, mod = build_sketch(key.kind, key.budget_kb * 1024, stats,
+                                       self.depth, key.seed, self.partitioner,
+                                       backend=self.sketch_backend)
+        return stream, sketch, mod
 
     def get(self, key: TenantKey) -> Tenant:
         return self._tenants[key]

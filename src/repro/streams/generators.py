@@ -1,8 +1,10 @@
 """Deterministic, seekable synthetic graph-stream generators.
 
-The paper benchmarks on unicorn-wget, email-EuAll and cit-HepPh; those files
-are not available offline, so we generate *statistically matched* streams:
-same node/edge counts, and power-law out/in-degree with per-dataset skew (the
+The paper benchmarks on unicorn-wget, email-EuAll and cit-HepPh; a fourth
+preset, SNAP sx-stackoverflow (63.5M temporal edges), is a stream long
+enough that an HBM-sized sketch is sub-linear in it.  Those files are not
+available offline, so we generate *statistically matched* streams: same
+node/edge counts, and power-law out/in-degree with per-dataset skew (the
 property the kMatrix partitioner exploits).  Real edge-list files are
 supported through ``FileStream`` when present on disk.
 
@@ -38,7 +40,12 @@ class StreamSpec:
 UNICORN_WGET = StreamSpec("unicorn-wget", 17_778, 277_972, 1.2, 1.1)
 EMAIL_EUALL = StreamSpec("email-EuAll", 265_214, 420_045, 1.35, 1.25)
 CIT_HEPPH = StreamSpec("cit-HepPh", 34_546, 421_578, 1.05, 1.3)
-DATASETS = {s.name: s for s in (UNICORN_WGET, EMAIL_EUALL, CIT_HEPPH)}
+# SNAP sx-stackoverflow: node and temporal-edge counts from the SNAP page;
+# the skews are not published and are assumed between the paper's streams.
+SX_STACKOVERFLOW = StreamSpec("sx-stackoverflow", 2_601_977, 63_497_050,
+                              1.2, 1.1)
+DATASETS = {s.name: s for s in (UNICORN_WGET, EMAIL_EUALL, CIT_HEPPH,
+                                SX_STACKOVERFLOW)}
 
 
 def _zipf_cdf(n: int, alpha: float) -> np.ndarray:

@@ -53,13 +53,19 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("d,p,w", [
-    (7, 7, 16), (7, 1, 512), (7, 1, 1024),
-    (1, 1, 520),  # row axis padded to whole 264-row blocks
-    (1, 1, MAX_WIDTH),  # the widest class KMatrixAccel.create accepts
+@pytest.mark.parametrize("d,p,w,c", [
+    pytest.param(7, 7, 16, 1024, id="7-7-16"),
+    pytest.param(7, 1, 512, 1024, id="7-1-512"),
+    pytest.param(7, 1, 1024, 1024, id="7-1-1024"),
+    # row axis padded to whole 264-row blocks
+    pytest.param(1, 1, 520, 1024, id="1-1-520"),
+    # the widest class KMatrixAccel.create accepts
+    pytest.param(1, 1, MAX_WIDTH, 1024, id=f"1-1-{MAX_WIDTH}"),
+    # sx-stackoverflow at 150 MiB: its three classes at a whole 8192-row
+    # dispatch, the capacity its plan's hottest partition sets
+    (7, 15, 256, 8192), (7, 1, 512, 8192), (7, 1, 1024, 8192),
 ])
-def test_matrix_ingest_compiles(one_chip, d, p, w):
-    c = 1024
+def test_matrix_ingest_compiles(one_chip, d, p, w, c):
     fn = jax.jit(lambda pool, hi, hj, wt: matrix_ingest(
         pool, hi, hj, wt, block_b=128, interpret=False))
     compiled = fn.lower(_int32((d, p, w, w), one_chip),
@@ -86,25 +92,45 @@ def test_reach_step_compiles(one_chip, w):
     _assert_kernel(fn.lower(reach).compile())
 
 
-@pytest.mark.parametrize("budget_kb", [512, 64 * 1024])
-def test_kmatrix_accel_ingest_step_compiles(one_chip, monkeypatch, budget_kb):
-    """The whole jitted ingest step of the launchers' cit-HepPh config
-    (scale 1.0, d=7, 8192-edge batches) at the default and a 64 MiB budget:
-    the latter has classes up to 512 wide and a 489-wide conn matrix."""
+@pytest.mark.parametrize("dataset,budget_kb", [
+    pytest.param("cit-HepPh", 512, id="512"),
+    pytest.param("cit-HepPh", 64 * 1024, id="65536"),
+    pytest.param("sx-stackoverflow", 153_600, id="sx-stackoverflow-153600"),
+])
+def test_kmatrix_accel_ingest_step_compiles(one_chip, monkeypatch, dataset,
+                                            budget_kb):
+    """The whole jitted ingest step (scale 1.0, d=7, 8192-edge batches) of
+    the launchers' cit-HepPh config at the default and a 64 MiB budget, and
+    of the 150 MiB sx-stackoverflow deployment.  64 MiB has classes up to
+    512 wide and a 489-wide conn matrix; 150 MiB has fifteen 256-wide
+    partitions, a 512 and a 1024 class and a 749-wide conn matrix, every
+    class at the whole batch."""
+    import dataclasses
+
     from repro.core import KMatrixAccel, vertex_stats_from_sample
+    from repro.core.kmatrix_accel import dispatch_capacity
     from repro.kernels import ops
-    from repro.streams import make_stream, sample_stream
+    from repro.streams import DATASETS, SyntheticStream, sample_stream
 
     # this process runs JAX on the CPU; the chip would take the Mosaic path
     monkeypatch.setattr(ops, "interpret_mode", lambda: False)
-    stream = make_stream("cit-HepPh", batch_size=8192, seed=0, scale=1.0)
+    spec = DATASETS[dataset]
+    if dataset == "sx-stackoverflow":
+        # the plan comes from the 30k-edge sample alone; sampling a 2M-edge
+        # prefix of the stream gives the same classes in a second
+        spec = dataclasses.replace(spec, n_edges=2_000_000)
+    stream = SyntheticStream(spec, batch_size=8192, seed=0)
     stats = vertex_stats_from_sample(*sample_stream(stream, 30_000, seed=1))
     sk = KMatrixAccel.create(bytes_budget=budget_kb * 1024, stats=stats,
                              depth=7, seed=0, partitioner="banded")
     if budget_kb == 512:
         assert max(sk.class_counts) > 1  # P_c > 1 classes
-    else:
+    elif dataset == "cit-HepPh":
         assert max(sk.class_widths) >= 512 and sk.conn_w == 489
+    else:
+        assert dict(zip(sk.class_widths, sk.class_counts)) == {
+            256: 15, 512: 1, 1024: 1}
+        assert sk.conn_w == 749 and dispatch_capacity(sk, 8192) == 8192
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         (sk, stream.batch(0)))

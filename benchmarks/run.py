@@ -459,7 +459,7 @@ def _capacity_policy_compare(stream, stats, quick: bool) -> dict:
         "n_batches": n_batches,
         "capacity_2bp": legacy,
         "capacity_plan": plan_cap,
-        "max_load_share": round(max(accel.load_shares), 4),
+        "max_load_share": round(max(map(max, accel.load_shares)), 4),
         "overflow_2bp_capacity": int(st_legacy.overflow),
         "overflow_plan_capacity": int(st_plan.overflow),
         "counters_equal": counters_equal,

@@ -90,10 +90,11 @@ class KMatrixAccel:
     conn_w: int = static_field()
     # Expected per-partition share of stream edges, from the partition
     # plan's banded load (sampled frequency mass per partition, Good-Turing
-    # share for the outlier).  Sizes the ingest dispatch capacity
-    # (``dispatch_capacity``) from the plan instead of a uniform 2B/P.
-    # None on sketches relayouted from a flat pool (no sample available):
-    # those fall back to the uniform formula.
+    # share for the outlier), arranged like the pools: ``load_shares[c][i]``
+    # is the share of row ``i`` of class ``c``.  Sizes each class's ingest
+    # dispatch capacity (``dispatch_capacity``) from the plan instead of a
+    # uniform 2B/P.  None on sketches relayouted from a flat pool (no sample
+    # available): those fall back to the uniform formula.
     load_shares: tuple | None = static_field(default=None)
 
     @property
@@ -152,7 +153,10 @@ class KMatrixAccel:
             jnp.zeros((depth, counts[c], classes[c], classes[c]), jnp.int32)
             for c in range(len(classes))
         )
-        load_shares = _plan_load_shares(plan, stats)
+        shares = _plan_load_shares(plan, stats)
+        load_shares = tuple(
+            tuple(shares[p] for p in np.nonzero(part_class == c)[0])
+            for c in range(len(classes)))
         return KMatrixAccel(
             pools=pools,
             conn=jnp.zeros((depth, conn_w, conn_w), jnp.int32),
@@ -191,27 +195,36 @@ def _plan_load_shares(plan, stats: VertexStats) -> tuple:
 
 
 def dispatch_capacity(sk: KMatrixAccel, batch_size: int,
-                      block_b: int = 128) -> int:
-    """Per-partition ingest dispatch capacity for one batch of ``batch_size``.
+                      block_b: int = 128) -> tuple:
+    """Per-partition ingest dispatch capacity of each width class, for one
+    dispatch of ``batch_size`` rows: a tuple with one entry per class.
 
-    Sized from the partition plan's banded load: the hottest partition's
-    expected share of the stream (``load_shares``) with 2x headroom, capped
-    at the batch size (a partition can never receive more than B edges, and
-    capacity == B guarantees a zero overflow tail).  The legacy uniform
-    ``2B/P`` is kept only as the fallback for relayouted sketches that carry
-    no sample — on skewed streams it undersizes the hot partition by the
-    skew factor and every excess edge pays the scatter-fallback path
-    (ROADMAP dispatch-capacity item; regression visible as
-    ``overflow_edges`` in runtime metrics / serve_bench / BENCH_ingest).
-    Rounded up to the Pallas ingest block so the kernel grid stays aligned.
+    Class ``c`` gets ``ceil(4 * s_c * B)`` slots, ``s_c`` the largest
+    expected stream share (``load_shares``) of its partitions, capped at the
+    batch size (a partition can never receive more than B rows, and
+    capacity == B guarantees it a zero overflow tail).  The 4 is 2x
+    headroom for batch-to-batch noise times 2, the most raw rows one
+    dispatched row stands for in the streams served (the worker's exact
+    dedup merges duplicate edges, about 2:1 on email-EuAll): the shares
+    count raw rows, the capacity counts dispatched ones.  Sizing each class
+    from its own load, not the hottest partition's, keeps a narrow class
+    of lightly loaded partitions from padding every one of them to the
+    whole dispatch: the kernel visits every slot of its ``[d, P_c, C_c]``
+    rectangle, filled or not.  The legacy uniform ``2B/P`` is kept only
+    as the fallback for relayouted sketches that carry no sample.  Edges
+    past a capacity take the exact scatter fallback and are tallied in
+    ``overflow`` (runtime metrics' ``overflow_edges``).  Each entry is at
+    least one block and rounded up to the Pallas ingest block so the
+    kernel grid stays aligned.
     """
+    floor = min(block_b, batch_size)
     if sk.load_shares:
-        cap = int(np.ceil(2.0 * max(sk.load_shares) * batch_size))
-        cap = min(cap, batch_size)
+        caps = [min(int(np.ceil(4.0 * max(s) * batch_size)), batch_size)
+                for s in sk.load_shares]
     else:
-        cap = (2 * batch_size) // max(sk.route.n_partitions, 1)
-    cap = max(cap, min(block_b, batch_size))
-    return -(-cap // block_b) * block_b
+        uniform = (2 * batch_size) // max(sk.route.n_partitions, 1)
+        caps = [uniform] * len(sk.class_widths)
+    return tuple(-(-max(cap, floor) // block_b) * block_b for cap in caps)
 
 
 def _class_structure(widths: np.ndarray):

@@ -11,8 +11,8 @@ Two consumption levels:
     into one (d, P_c, w_c, w_c) array per class — every block static, no
     scalar-prefetch offsets, and ingest batches become per-class MXU
     matmuls.  Edges are bucketed to (partition, slot) rectangles with a
-    capacity factor; a sketch must count EVERY edge, so capacity overflow
-    falls back to an exact in-jit scatter (never drops, unlike MoE).
+    capacity per width class; a sketch must count EVERY edge, so capacity
+    overflow falls back to an exact in-jit scatter (never drops, unlike MoE).
 
 Interpret mode is decided per call (``interpret_mode``): kernels compile
 through Mosaic when JAX's default backend is a TPU and run in the Pallas
@@ -104,12 +104,13 @@ def accel_reach_closure(table: jax.Array, *, block: int = 128,
 # ingest dispatch; the names below are re-exported for kernel-level callers.
 
 
-def _dispatch(sk: KMatrixAccel, batch: EdgeBatch, capacity: int):
-    """Bucket edges into per-partition rectangles (P, C) + overflow mask.
+def _dispatch(sk: KMatrixAccel, batch: EdgeBatch, capacity: tuple):
+    """Bucket edges into per-partition rectangles (P_c, C_c) + overflow mask.
 
     Returns (slot, part, in_capacity): slot[e] is the edge's rank within its
     partition (stable), computed with one argsort — the TPU-friendly
-    alternative to atomic counters.
+    alternative to atomic counters; an edge is in capacity when its rank is
+    below its class's entry of ``capacity``.
     """
     p = sk.route.lookup(batch.src)  # [B]
     p = jnp.where(batch.weight > 0, p, jnp.int32(sk.route.n_partitions))  # park padding
@@ -123,23 +124,29 @@ def _dispatch(sk: KMatrixAccel, batch: EdgeBatch, capacity: int):
     start_of_group = jax.lax.associative_scan(jnp.maximum, start_pos)
     rank_sorted = pos - start_of_group
     rank = jnp.zeros_like(rank_sorted).at[order].set(rank_sorted)
-    in_cap = (rank < capacity) & (batch.weight > 0)
+    cap = jnp.asarray(capacity, jnp.int32)[sk.part_class[p]]
+    in_cap = (rank < cap) & (batch.weight > 0)
     return p, rank, in_cap
 
 
 def kmatrix_accel_ingest(sk: KMatrixAccel, batch: EdgeBatch,
-                         *, capacity: int | None = None,
+                         *, capacity: int | tuple | None = None,
                          block_b: int = 128) -> KMatrixAccel:
     """Exact batched ingest: per-class Pallas matmul ingest for edges within
-    capacity, in-jit scatter fallback for the overflow tail (no drops)."""
-    b = batch.size
+    capacity, in-jit scatter fallback for the overflow tail (no drops).
+
+    ``capacity`` is the per-partition dispatch capacity: one int for every
+    class, a tuple with one entry per width class, or None for the plan's
+    per-class sizing (``dispatch_capacity``).  Each entry is rounded up to
+    ``block_b``."""
     if capacity is None:
-        # sized from the partition plan's banded load (hottest partition's
-        # expected share of the batch), NOT a uniform 2B/P — on skewed
-        # streams the hot partition's load exceeds 2B/P by the skew factor
-        # and every excess edge would pay the scatter fallback
-        capacity = dispatch_capacity(sk, b, block_b)
-    capacity = -(-capacity // block_b) * block_b
+        capacity = dispatch_capacity(sk, batch.size, block_b)
+    elif not isinstance(capacity, (tuple, list)):
+        capacity = (capacity,) * len(sk.class_widths)
+    if len(capacity) != len(sk.class_widths):
+        raise ValueError(f"capacity {capacity} has not one entry per width "
+                         f"class {sk.class_widths}")
+    capacity = tuple(-(-int(c) // block_b) * block_b for c in capacity)
 
     p, rank, in_cap = _dispatch(sk, batch, capacity)
     d = sk.depth
@@ -147,27 +154,28 @@ def kmatrix_accel_ingest(sk: KMatrixAccel, batch: EdgeBatch,
     mix_dst = sk.hashes.mix(batch.dst)
 
     pools = list(sk.pools)
-    for c, (w_c, p_c) in enumerate(zip(sk.class_widths, sk.class_counts)):
+    for c, (w_c, p_c, c_c) in enumerate(
+            zip(sk.class_widths, sk.class_counts, capacity)):
         if p_c == 0:
             continue
         sel = in_cap & (sk.part_class[p] == c)
         q = jnp.where(sel, sk.part_index[p], 0)
-        # Park unselected edges at slot == capacity: out of bounds, dropped.
+        # Park unselected edges at slot == C_c: out of bounds, dropped.
         # (Parking *in bounds* would let a parked .set(0) race a real edge.)
-        slot = jnp.where(sel, rank, capacity)
+        slot = jnp.where(sel, rank, c_c)
         hi = fastrange(mix_src, w_c)  # [d, B]
         hj = fastrange(mix_dst, w_c)
-        # Scatter edges into the (P_c, C) rectangle (weight 0 elsewhere).
-        hi_r = jnp.zeros((d, p_c, capacity), jnp.int32).at[:, q, slot].set(
+        # Scatter edges into the (P_c, C_c) rectangle (weight 0 elsewhere).
+        hi_r = jnp.zeros((d, p_c, c_c), jnp.int32).at[:, q, slot].set(
             jnp.where(sel[None], hi, 0), mode="drop")
-        hj_r = jnp.zeros((d, p_c, capacity), jnp.int32).at[:, q, slot].set(
+        hj_r = jnp.zeros((d, p_c, c_c), jnp.int32).at[:, q, slot].set(
             jnp.where(sel[None], hj, 0), mode="drop")
-        wt_r = jnp.zeros((p_c, capacity), jnp.int32).at[q, slot].add(
+        wt_r = jnp.zeros((p_c, c_c), jnp.int32).at[q, slot].add(
             jnp.where(sel, batch.weight, 0), mode="drop")
         pools[c] = matrix_ingest(pools[c], hi_r, hj_r, wt_r,
                                  block_b=block_b, interpret=interpret_mode())
 
-    # Overflow tail: exact scatter (rare; only when a partition exceeds cap).
+    # Overflow tail: exact scatter (rare; only when a partition exceeds C_c).
     # The tally is surfaced as sk.overflow so capacity regressions show up
     # in runtime metrics instead of silently eating scatter-fallback cost.
     over = (~in_cap) & (batch.weight > 0)

@@ -122,33 +122,99 @@ def test_flat_overflow_leaf_is_inert_and_additive():
 
 
 def test_dispatch_capacity_sized_from_plan_load():
-    """ISSUE 4 satellite: default dispatch capacity comes from the
-    partition plan's banded load (max per-partition share, 2x headroom,
-    capped at B), not the uniform 2B/P — and capacity is a dispatch-only
-    concern: counters are bit-identical under any capacity."""
+    """Default dispatch capacity is one entry per width class, each sized
+    from its own class's hottest expected share of the stream (4x: 2x
+    headroom times the dedup factor), rounded up to the Pallas block and
+    capped at B; the hottest class gets B.  Relayouted sketches carry no
+    sample and fall back to the uniform 2B/P for every class.  Capacity is
+    a dispatch-only concern: counters are bit-identical under any."""
     src, dst, w = _random_stream(0)
     stats = vertex_stats_from_sample(src[:1000], dst[:1000], w[:1000])
     acc = KMatrixAccel.create(bytes_budget=1 << 16, stats=stats, depth=3,
                               seed=1, partitioner="banded")
     assert acc.load_shares is not None
-    assert len(acc.load_shares) == acc.route.n_partitions
-    assert 0.99 <= sum(acc.load_shares) <= 1.01
+    assert tuple(len(s) for s in acc.load_shares) == acc.class_counts
+    assert 0.99 <= sum(map(sum, acc.load_shares)) <= 1.01
+    assert len(acc.class_widths) > 1
     b = 4096
-    cap = kma.dispatch_capacity(acc, b)
-    want = int(np.ceil(2.0 * max(acc.load_shares) * b))
-    assert cap >= min(want, b) and cap % 128 == 0 and cap <= b + 127
+    caps = kma.dispatch_capacity(acc, b)
+    assert len(caps) == len(acc.class_widths)
+    for cap, shares in zip(caps, acc.load_shares):
+        want = max(min(int(np.ceil(4.0 * max(shares) * b)), b), 128)
+        assert cap % 128 == 0 and want <= cap < want + 128
+    hot = max(range(len(caps)), key=lambda c: max(acc.load_shares[c]))
+    assert 4.0 * max(acc.load_shares[hot]) >= 1.0 and caps[hot] == b
+    assert min(caps) < b  # a lightly loaded class is not padded to B
     # relayouted sketches carry no sample: uniform fallback
     relayout = kma.to_class_layout(kma.to_flat_layout(acc))
     assert relayout.load_shares is None
     legacy = kma.dispatch_capacity(relayout, b)
-    assert legacy == -(-max(128, (2 * b) // acc.route.n_partitions)
-                       // 128) * 128
+    uniform = -(-max(128, (2 * b) // acc.route.n_partitions) // 128) * 128
+    assert legacy == (uniform,) * len(acc.class_widths)
     # capacity never changes counters, only the MXU/scatter split
     batch = EdgeBatch.from_numpy(*_random_stream(55))
     a = kma.ingest(acc, batch)                    # plan-derived default
     bb = kma.ingest(acc, batch, capacity=legacy)  # legacy uniform
     assert _leaves_equal(a.pools, bb.pools)
     np.testing.assert_array_equal(np.asarray(a.conn), np.asarray(bb.conn))
+
+
+@pytest.mark.parametrize("dataset", ["email-EuAll", "cit-HepPh"])
+def test_dispatch_capacity_holds_every_partition_of_a_dispatch(dataset):
+    """The 1 MB, d = 7 sketches of the email-EuAll and cit-HepPh streams
+    (the program's own banded plans from the 30k-edge sample): over the
+    first 60 client batches of 8192 edges, deduplicated as the ingest
+    worker does and padded to its 2048-row granule, no partition receives
+    more rows than its class's capacity.  Routing alone, no kernel."""
+    from repro.runtime.worker import preaggregate_edges
+    from repro.streams import make_stream, sample_stream
+
+    stream = make_stream(dataset, batch_size=8192, seed=0, scale=1.0)
+    stats = vertex_stats_from_sample(*sample_stream(stream, 30_000, seed=1))
+    sk = KMatrixAccel.create(bytes_budget=1 << 20, stats=stats, depth=7,
+                             seed=0, partitioner="banded")
+    n_parts = sk.route.n_partitions
+    part_class = np.asarray(sk.part_class)
+    rows = [preaggregate_edges(*stream.batch_numpy(i % stream.num_batches))[0]
+            for i in range(60)]
+    parts = np.asarray(sk.route.lookup(jnp.asarray(np.concatenate(rows))))
+    ends = np.cumsum([len(r) for r in rows])
+    narrow = False
+    for lo, hi in zip(np.concatenate([[0], ends[:-1]]), ends):
+        b = -(-int(hi - lo) // 2048) * 2048  # IngestWorker.dispatch_granule
+        caps = np.asarray(kma.dispatch_capacity(sk, b))
+        got = np.bincount(parts[lo:hi], minlength=n_parts)
+        assert np.all(got <= caps[part_class]), (b, got, caps)
+        narrow |= bool(caps.min() < b)
+    assert narrow  # the narrow classes do get less than the whole dispatch
+
+
+def test_per_class_capacity_overflow_is_exact_and_counted():
+    """Per-class capacities small enough to force overflow give counters
+    bit-identical to capacity = B, with ``overflow`` the edges past their
+    class's capacity; an int means one capacity for every class."""
+    acc = _accel(seed=5)
+    src, dst, w = _random_stream(77)
+    batch = EdgeBatch.from_numpy(src, dst, w)
+    b = batch.size
+    n_cls = len(acc.class_widths)
+    assert n_cls > 1
+    caps = (128,) * (n_cls - 1) + (b,)  # narrow classes overflow, hot not
+    full = kma.ingest(acc, batch, capacity=b)
+    small = kma.ingest(acc, batch, capacity=caps)
+    assert int(full.overflow) == 0
+    assert _leaves_equal(small.pools, full.pools)
+    np.testing.assert_array_equal(np.asarray(small.conn),
+                                  np.asarray(full.conn))
+    parts = np.asarray(acc.route.lookup(jnp.asarray(src[w > 0])))
+    got = np.bincount(parts, minlength=acc.route.n_partitions)
+    past = np.maximum(got - np.asarray(caps)[np.asarray(acc.part_class)], 0)
+    assert int(small.overflow) == int(past.sum()) > 0
+    same = kma.ingest(acc, batch, capacity=128)
+    assert _leaves_equal(same, kma.ingest(acc, batch,
+                                          capacity=(128,) * n_cls))
+    with pytest.raises(ValueError, match="one entry per width class"):
+        kma.ingest(acc, batch, capacity=(128,) * (n_cls + 1))
 
 
 def test_to_class_layout_rejects_unquantized_plan():

@@ -9,7 +9,8 @@ per-class one-hot matmuls (``repro.kernels.matrix_ingest``) instead of a
 serialized XLA scatter.
 
 This module is the *sketch protocol* surface the production layers consume
-(serving registry/snapshots, runtime workers, checkpoints, benchmarks):
+(serving registry/snapshots, runtime workers, checkpoints, the chip
+benchmark):
 ``create / ingest / edge_freq / node_out_freq / conn_cells / empty_like /
 merge`` — mirror-compatible with ``repro.core.kmatrix`` so every layer above
 is layout-agnostic.  Only ``ingest`` touches Pallas (lazily, via
@@ -20,8 +21,8 @@ Layout equivalence: the class layout and the flat layout index the *same*
 cells — cell ``(hi, hj)`` of partition ``p`` is ``pools[class(p)][d,
 index(p), hi, hj]`` here and ``pool[d, offset(p) + hi*w_p + hj]`` there.
 ``to_flat_layout`` / ``to_class_layout`` apply that permutation bit-exactly,
-so checkpoints written under either backend load into the other and
-``benchmarks/serve_bench.py`` can hard-gate estimate equality.
+so checkpoints written under either backend load into the other and the
+tests can assert bit-exact parity between the two.
 
 Backend selection (``sketch_backend``): explicit arg > $REPRO_SKETCH_BACKEND
 > platform default — ``pallas`` on TPU, ``flat`` elsewhere (the pallas path

@@ -7,19 +7,16 @@ Each kernel ships three pieces (see EXAMPLE.md):
 
 Kernels:
   matrix_ingest  — sketch ingest as one-hot MXU matmul accumulation
-  matrix_lookup  — batched point queries (gather+min) via MXU
   reach_closure  — tiled boolean matmul squaring (reachability)
   embedding_bag  — scalar-prefetch row-gather + segment reduce (recsys)
 """
 from repro.kernels.matrix_ingest import matrix_ingest
-from repro.kernels.matrix_lookup import matrix_lookup
 from repro.kernels.reach_closure import reach_step
 from repro.kernels.embedding_bag import embedding_bag
 from repro.kernels import ops, ref
 
 __all__ = [
     "matrix_ingest",
-    "matrix_lookup",
     "reach_step",
     "embedding_bag",
     "ops",

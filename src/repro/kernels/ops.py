@@ -1,10 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Two consumption levels:
-
-  * Global matrix sketches (TCM/gMatrix): drop-in accelerated ingest/lookup
-    (`accel_matrix_ingest` / `accel_matrix_edge_freq`) on the (d, w, w)
-    table — P=1 instances of the kernels.
+Two consumers:
 
   * kMatrix: the TPU-native `KMatrixAccel` state. Partition widths are
     quantized to power-of-two *width classes* so the pool rectangularizes
@@ -13,6 +9,9 @@ Two consumption levels:
     matmuls.  Edges are bucketed to (partition, slot) rectangles with a
     capacity per width class; a sketch must count EVERY edge, so capacity
     overflow falls back to an exact in-jit scatter (never drops, unlike MoE).
+
+  * Reachability: `accel_reach_closure`, the boolean closure of a (d, w, w)
+    table by repeated tiled squaring (`reach_step`).
 
 Interpret mode is decided per call (``interpret_mode``): kernels compile
 through Mosaic when JAX's default backend is a TPU and run in the Pallas
@@ -28,10 +27,8 @@ import jax.numpy as jnp
 from repro.common.hashing import fastrange
 from repro.core.kmatrix_accel import KMatrixAccel, dispatch_capacity
 from repro.core.kmatrix_accel import edge_freq as kmatrix_accel_edge_freq  # noqa: F401 (kernel-level re-export)
-from repro.core.matrix_sketch import MatrixSketch
 from repro.core.types import EdgeBatch
 from repro.kernels.matrix_ingest import matrix_ingest
-from repro.kernels.matrix_lookup import matrix_lookup
 from repro.kernels.reach_closure import reach_step
 from repro.kernels.embedding_bag import embedding_bag  # re-export
 
@@ -40,40 +37,6 @@ def interpret_mode() -> bool:
     """True unless JAX's default backend is a TPU.  Asked at call time, never
     at import, so the answer tracks the backend JAX actually started."""
     return jax.default_backend() != "tpu"
-
-
-def _pad_edges(x: jax.Array, block: int, fill=0) -> jax.Array:
-    b = x.shape[-1]
-    pad = (-b) % block
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
-    return jnp.pad(x, widths, constant_values=fill)
-
-
-# --------------------------------------------------------------------------
-# Global (d, w, w) matrix sketches: P = 1
-# --------------------------------------------------------------------------
-
-def accel_matrix_ingest(sk: MatrixSketch, batch: EdgeBatch,
-                        *, block_b: int = 256) -> MatrixSketch:
-    hi = fastrange(sk.hashes.mix(batch.src), sk.w)  # [d, B]
-    hj = fastrange(sk.hashes.mix(batch.dst), sk.w)
-    hi = _pad_edges(hi, block_b)[:, None, :]  # [d, 1, C]
-    hj = _pad_edges(hj, block_b)[:, None, :]
-    wt = _pad_edges(batch.weight, block_b)[None, :]  # [1, C]
-    table = matrix_ingest(sk.table[:, None], hi, hj, wt, block_b=block_b,
-                          interpret=interpret_mode())[:, 0]
-    return sk.replace(table=table)
-
-
-def accel_matrix_edge_freq(sk: MatrixSketch, src: jax.Array, dst: jax.Array,
-                           *, block_q: int = 256) -> jax.Array:
-    hi = _pad_edges(fastrange(sk.hashes.mix(src), sk.w), block_q)[:, None, :]
-    hj = _pad_edges(fastrange(sk.hashes.mix(dst), sk.w), block_q)[:, None, :]
-    est = matrix_lookup(sk.table[:, None], hi, hj, block_q=block_q,
-                        interpret=interpret_mode())
-    return est[0, : src.shape[-1]]
 
 
 def accel_reach_closure(table: jax.Array, *, block: int = 128,

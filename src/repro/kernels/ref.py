@@ -27,17 +27,6 @@ def matrix_ingest_ref(pool: jax.Array, hi: jax.Array, hj: jax.Array,
     )
 
 
-def matrix_lookup_ref(pool: jax.Array, hi: jax.Array, hj: jax.Array) -> jax.Array:
-    """Point queries: min over layers of the addressed cells.
-
-    pool: int32[d, P, w, w]   hi/hj: int32[d, P, C]  ->  int32[P, C]
-    """
-    d, p, w, _ = pool.shape
-    rows = jnp.arange(d, dtype=jnp.int32)[:, None, None]
-    parts = jnp.arange(p, dtype=jnp.int32)[None, :, None]
-    return jnp.min(pool[rows, parts, hi, hj], axis=0)
-
-
 def reach_step_ref(reach: jax.Array) -> jax.Array:
     """One boolean-closure squaring step: R <- min(R @ R, 1), R: f32[w, w]."""
     return jnp.minimum(

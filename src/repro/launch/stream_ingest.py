@@ -13,7 +13,7 @@ Worker-host mode (DESIGN.md §Net): ``--listen HOST:PORT`` turns this
 process into a standing socket-ingest worker host — it serves ingest
 worker sessions for any parent running a ``socket``-backend Runtime
 pointed at this address (``--runtime-backend socket:HOST:PORT`` here or
-in query_serve / serve_bench).  All other pipeline flags are ignored in
+in query_serve).  All other pipeline flags are ignored in
 this mode: the tenant spec arrives over the wire in the hello frame.
 """
 from __future__ import annotations

@@ -9,7 +9,7 @@
 """
 from repro.obs.hub import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsHub, LADDERS,
-    get_hub, reset_hub, set_disabled, metrics_disabled,
+    get_hub, reset_hub,
     render_prometheus, quantile_from_state, merge_hist_states, hist_summary,
 )
 from repro.obs.trace import (  # noqa: F401

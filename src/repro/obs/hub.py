@@ -18,9 +18,6 @@ Topology (DESIGN.md §Observability):
   double-counts
 - ``merged_state()`` / ``render_prometheus()`` fold local + adopted
   states: counters and histogram buckets add, gauges last-write-wins
-
-``set_disabled(True)`` turns every instrument mutation into an early
-return; ``benchmarks/run.py obs`` uses it for the metrics-off arm.
 """
 from __future__ import annotations
 
@@ -31,8 +28,7 @@ from typing import Any, Callable
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsHub",
-    "get_hub", "reset_hub", "set_disabled", "metrics_disabled",
-    "LADDERS",
+    "get_hub", "reset_hub", "LADDERS",
 ]
 
 # ---------------------------------------------------------------- ladders
@@ -44,20 +40,6 @@ LADDERS: dict[str, tuple[float, ...]] = {
     "latency": tuple(1e-6 * (2.0 ** (i / 2.0)) for i in range(54)),
     "size": tuple(float(2 ** i) for i in range(25)),
 }
-
-_disabled = False
-
-
-def set_disabled(flag: bool) -> None:
-    """Globally disable (or re-enable) instrument mutation — the
-    metrics-off arm of the overhead benchmark."""
-    global _disabled
-    _disabled = bool(flag)
-
-
-def metrics_disabled() -> bool:
-    return _disabled
-
 
 def _label_key(labels: dict[str, Any]) -> tuple:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
@@ -95,14 +77,10 @@ class Counter:
         self._lock = threading.Lock()
 
     def inc(self, n: float = 1.0) -> None:
-        if _disabled:
-            return
         with self._lock:
             self.value += n
 
     def set(self, v: float) -> None:
-        if _disabled:
-            return
         self.value = float(v)
 
 
@@ -117,13 +95,9 @@ class Gauge:
         self.value = 0.0
 
     def set(self, v: float) -> None:
-        if _disabled:
-            return
         self.value = float(v)
 
     def inc(self, n: float = 1.0) -> None:
-        if _disabled:
-            return
         self.value += n
 
 
@@ -155,8 +129,6 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, value: float) -> None:
-        if _disabled:
-            return
         v = float(value)
         idx = bisect_left(self.bounds, v)
         with self._lock:
@@ -169,15 +141,13 @@ class Histogram:
                 self.max = v
 
     def observe_many(self, values) -> None:
-        if _disabled:
-            return
         for v in values:
             self.observe(v)
 
     def observe_n(self, value: float, n: int) -> None:
         """Record ``n`` occurrences of ``value`` in one bucket update
         (e.g. per-request weighting of a per-batch latency)."""
-        if _disabled or n <= 0:
+        if n <= 0:
             return
         v = float(value)
         idx = bisect_left(self.bounds, v)

@@ -28,10 +28,7 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import Any, NamedTuple
-
-from repro.obs.hub import metrics_disabled
 
 __all__ = ["new_trace_id", "Span", "TraceLog", "get_trace_log",
            "reset_trace_log"]
@@ -40,8 +37,6 @@ DEFAULT_CAPACITY = 4096
 # Holds a 51 s window at about 2,000 spans/s, the rate of a saturated
 # ingest worker (about 7 spans per dispatch).
 SPAN_CAPACITY = 1 << 17
-
-_OFF = nullcontext()
 
 
 class Span(NamedTuple):
@@ -90,7 +85,7 @@ class TraceLog:
 
     def emit(self, trace_id: str, span: str, event: str,
              **attrs: Any) -> None:
-        if not trace_id or metrics_disabled():
+        if not trace_id:
             return
         rec = {"ts": time.time(), "trace": trace_id, "span": span,
                "event": event}
@@ -101,10 +96,7 @@ class TraceLog:
             self._emitted += 1
 
     def span(self, name: str, key: Any = None):
-        """Context manager timing one host phase (see the module doc).
-        A no-op while the metrics kill switch is set."""
-        if metrics_disabled():
-            return _OFF
+        """Context manager timing one host phase (see the module doc)."""
         return _SpanScope(self, name, key)
 
     def record_span(self, name: str, t0_ns: int, t1_ns: int,

@@ -18,8 +18,8 @@ snapshot publications back into the parent's ``SnapshotBuffer`` — K-shard
 ingest then scales past the GIL.
 
 Entry points: ``launch/query_serve.py --background-ingest
-[--runtime-backend process]`` and ``benchmarks/serve_bench.py
---concurrent`` / ``--shards K``.
+[--runtime-backend process] [--shards K]`` and the chip benchmark
+(``bench/``).
 """
 from repro.runtime.backend import (
     ExecutionBackend,

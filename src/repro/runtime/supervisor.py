@@ -1,7 +1,7 @@
 """Runtime supervisor: background ingest behind the serving engine.
 
 ``Runtime`` owns the concurrency story that `launch/query_serve.py` and
-`benchmarks/serve_bench.py --concurrent` build on: per tenant, a
+the chip benchmark (`bench/`) build on: per tenant, a
 ``StreamPump`` thread reads the seekable stream and feeds a
 ``BoundedEdgeQueue`` (explicit backpressure), a worker built by the
 configured **execution backend** (``backend="thread"`` — the classic

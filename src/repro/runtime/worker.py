@@ -66,7 +66,7 @@ def preaggregate_edges(src: np.ndarray, dst: np.ndarray,
     Returns ``(usrc, udst, uweight)`` int32 arrays with one row per
     distinct (src, dst) pair, weights summed, zero-sum rows dropped.
 
-    Bit-exactness argument (gated by the BENCH_ingest A/B cells): sketch
+    Bit-exactness argument (tests/test_ingest_fastpath.py): sketch
     counters are linear — every update is ``cell += weight`` — and int32
     addition modulo 2^32 is commutative and associative, so scattering one
     summed row is bit-identical to scattering each duplicate in turn.  The

@@ -10,7 +10,7 @@ Turns the offline reproduction into an always-on service (DESIGN.md
   loadgen   open-loop load generator reporting QPS and p50/p99 latency
 
 Entry points: ``launch/query_serve.py`` (ingest + serving end to end) and
-``benchmarks/serve_bench.py`` (the BENCH trajectory's serving row).
+the chip benchmark (``bench/``).
 """
 from repro.serving.engine import (
     ClosureCache,
@@ -41,11 +41,9 @@ from repro.serving.sharding import (
     ShardedSnapshot,
     ShardedTenant,
     attach_shards,
-    measure_sharded_ingest,
     read_shard_manifest,
     sharded_conservation,
     sharded_direct_answers,
-    warm_ingest_shapes,
     write_shard_manifest,
 )
 from repro.serving.snapshot import Snapshot, SnapshotBuffer
@@ -57,11 +55,9 @@ __all__ = [
     "ShardedSnapshot",
     "ShardedTenant",
     "attach_shards",
-    "measure_sharded_ingest",
     "read_shard_manifest",
     "sharded_conservation",
     "sharded_direct_answers",
-    "warm_ingest_shapes",
     "write_shard_manifest",
     "ClosureCache",
     "QueryEngine",

@@ -390,7 +390,7 @@ def direct_answers(snapshot: Snapshot, requests: list[Request]) -> list[Any]:
     """Reference oracle: answer each request one-by-one through the
     module-level ``repro.core`` query functions (no planner, no padding, no
     closure cache).  The engine must match this exactly for the same
-    snapshot — asserted by tests/test_serving.py and benchmarks/serve_bench.
+    snapshot — asserted by tests/test_serving.py.
     """
     sk = snapshot.sketch
     mod = sketch_module(sk)

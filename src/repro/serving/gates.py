@@ -1,9 +1,8 @@
 """Shared hard-gate helpers: conservation + exactness checks for serving.
 
 One implementation for every drive path that gates on correctness —
-``serve_bench --concurrent``, ``serve_bench --shards`` (thread AND process
-runtime backends), ``benchmarks/run.py`` and the test suite — instead of
-the per-bench copies these started as.  Everything here is pure checking:
+``launch/query_serve.py``, ``serving/sharding.py``, ``chip_smoke.py`` and
+the test suite.  Everything here is pure checking:
 no timing, no I/O, no policy.
 
 The two invariant families (DESIGN.md §Runtime / §Sharding):

@@ -8,7 +8,7 @@ of isolation for the always-on query service.  The registry owns, per tenant:
   * the ingest loop position (next unread batch), and
   * the ``SnapshotBuffer`` holding the live delta + published snapshot.
 
-``launch/query_serve.py`` and ``benchmarks/serve_bench.py`` drive tenants by
+``launch/query_serve.py`` (cooperative mode) drives tenants by
 alternating ``tenant.step(n)`` (ingest) with engine query batches against
 ``tenant.snapshot`` — the double buffer guarantees the queries stay
 epoch-consistent while ingest runs.

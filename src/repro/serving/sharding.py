@@ -12,8 +12,8 @@ shards partition the stream and share a layout:
     (``attach_shards``), each publishing epochs independently;
   * the merge of all shard sketches is bit-identical to a single sketch
     that ingested the whole stream (counter additivity over a stream
-    partition) — ``merged_snapshot`` is the gate `serve_bench --shards`
-    hard-fails on;
+    partition) — ``merged_snapshot`` is what tests/test_sharding.py
+    checks against a single-sketch replay;
   * queries scatter/gather (``ShardedQueryEngine``): edge-frequency and
     node-out route to the owning shard alone (all out-edges of a vertex
     live there), node-in / path / subgraph decompose per edge pair and sum,
@@ -33,7 +33,6 @@ import functools
 import json
 import os
 import tempfile
-import time
 
 import numpy as np
 import jax.numpy as jnp
@@ -249,8 +248,8 @@ class ShardedQueryEngine:
                                 owning shard's score; union, sorted by id
 
     Exact by construction: ``sharded_direct_answers`` computes the same
-    composition through the module-level query functions, and
-    tests/serve_bench hard-gate equality.
+    composition through the module-level query functions, and the tests
+    assert equality.
     """
 
     def __init__(self, engine: eng.QueryEngine | None = None,
@@ -394,7 +393,7 @@ def sharded_direct_answers(ssnap: ShardedSnapshot,
     composition as ``ShardedQueryEngine`` but answered request-by-request
     through the module-level query functions (no planner, no padding, no
     caches).  The sharded engine must match this exactly — asserted by
-    tests/test_sharding.py and ``serve_bench --shards``."""
+    tests/test_sharding.py."""
     plan = ssnap.plan
     parts = ssnap.parts
     mod = eng.sketch_module(parts[0].sketch)
@@ -503,7 +502,7 @@ def attach_shards(runtime, tenant: ShardedTenant, *, restore: bool = False,
 def sharded_conservation(handles, stream_total: int) -> dict:
     """Cross-shard edge-mass accounting over per-shard runtime handles.
 
-    The hard gate (`serve_bench --shards`): the shard views partition the
+    The invariant (tests/test_sharding.py): the shard views partition the
     stream, so after a graceful drain Σ per-shard published + Σ accounted
     drops must equal the base stream's total — and every shard must
     individually balance (zero unaccounted).
@@ -520,109 +519,6 @@ def sharded_conservation(handles, stream_total: int) -> dict:
         **verdict,
         "per_shard_published": [c["published_edges"] for c in per_shard],
         "per_shard_unaccounted": unaccounted,
-    }
-
-
-def warm_ingest_shapes(tenant: ShardedTenant) -> int:
-    """Compile every shard-ingest bucket shape off the clock.
-
-    Ingests zero-weight batches (a counter no-op: additive sketches ignore
-    weight-0 updates) of each ladder bucket through each shard's buffer.
-    Covers up to 2x the base batch: worker coalescing may overshoot its
-    target by one item, so coalesced dispatches can reach ~2B.  With the
-    shared per-module kernel cache (serving/snapshot.py) each shape
-    compiles ONCE per process regardless of K.  Returns the number of
-    shapes touched.
-    """
-    shapes = 0
-    for shard in tenant.shards:
-        view = shard.stream
-        base_b = getattr(view.base, "batch_size", view.granule * 4)
-        for bucket in range(view.granule, 2 * base_b + view.granule,
-                            view.granule):
-            z = np.zeros(bucket, np.int32)
-            shard.buffer.ingest(EdgeBatch.from_numpy(z, z, z))
-            shapes += 1
-    # also compile the publish (merge + re-zero) kernel: publishing the
-    # still-zero delta is a no-op on counters (it does bump each shard's
-    # epoch by one, which is harmless — epoch numbers are arbitrary)
-    for shard in tenant.shards:
-        shard.publish()
-    return shapes
-
-
-def measure_sharded_ingest(tenant: ShardedTenant, *,
-                           backend: str = "thread",
-                           coalesce_batches: int = 16,
-                           max_batches: int | None = None) -> dict:
-    """Backlog-drain ingest throughput over K shard workers, any backend.
-
-    Pre-fills each shard's parent-side queue with its (remaining) stream
-    view, then drains through one ``Runtime`` worker per shard — no pumps,
-    no query load.  This is the pure concurrent-ingest capacity number
-    ``benchmarks/run.py`` charts against K (and thread-vs-process in
-    ``BENCH_process.json``).  The wall runs from each worker's first-ingest
-    monotonic timestamp to its drain-publish timestamp (system-wide clock
-    on Linux, so valid across the process boundary): stream generation,
-    spawn, jit warm-up and readiness handshakes are all off the clock for
-    every backend, while the publish end-point synchronizes on the device
-    ingest chain (the pending-count fetch), so async dispatch cannot hide
-    compute off the clock.  Conservation-checked: every queued edge must
-    land in a published epoch.
-    """
-    from repro.runtime import QueueItem, Runtime
-
-    nb = tenant.stream.num_batches
-    coalesce_target = getattr(tenant.stream, "batch_size", 8192)
-    per_shard_items: list[list] = []
-    queued_edges = 0
-    for shard in tenant.shards:
-        end = nb if max_batches is None else min(nb, shard.offset
-                                                 + max_batches)
-        items = []
-        for i in range(shard.offset, end):
-            src, dst, w = shard.stream.batch_numpy(i)
-            item = QueueItem.from_arrays(i, src, dst, w)
-            items.append(item)
-            queued_edges += item.n_edges
-        per_shard_items.append(items)
-    capacity = max(max((len(x) for x in per_shard_items), default=0), 1) + 1
-    # publish once at drain: per-epoch cadence is a serving concern and
-    # would bill one full-sketch merge per epoch to the ingest wall
-    runtime = Runtime(queue_capacity=capacity,
-                      publish_policy="every:1000000000", reservoir_k=0,
-                      poll_s=0.002, backend=backend,
-                      coalesce_batches=coalesce_batches,
-                      coalesce_target=coalesce_target)
-    handles = [runtime.attach(shard, pump=False) for shard in tenant.shards]
-    if not runtime.backend.remote:
-        warm_ingest_shapes(tenant)  # process children warm on their side
-    runtime.start()
-    runtime.wait_ready()  # ALL workers up before the backlog lands: the
-    #                       wall must measure the concurrent drain, not
-    #                       K staggered child boots
-    base_edges = sum(h.worker.base_edges for h in handles)
-    for handle, items in zip(handles, per_shard_items):
-        for item in items:
-            handle.queue.put(item)  # capacity covers the whole backlog
-    runtime.stop(drain=True, timeout=600)
-    metrics = [h.worker.metrics_snapshot() for h in handles]
-    starts = [m["first_ingest_at"] for m in metrics if m["first_ingest_at"]]
-    ends = [m["last_publish_at"] for m in metrics if m["last_publish_at"]]
-    wall = max((max(ends) - min(starts)) if starts and ends else 0.0, 1e-9)
-    ingested = sum(h.worker.ingested_edges for h in handles)
-    published = sum(s.snapshot.n_edges for s in tenant.shards)
-    return {
-        "n_shards": tenant.n_shards,
-        "backend": runtime.backend.name,
-        "queued_edges": queued_edges,
-        "ingested_edges": ingested,
-        "published_edges": published,
-        "wall_s": round(wall, 4),
-        "edges_per_s": round(ingested / wall, 1),
-        "worker_states": [h.worker.state for h in handles],
-        "conserved": bool(ingested == queued_edges
-                          and published - base_edges == ingested),
     }
 
 

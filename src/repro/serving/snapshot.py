@@ -85,7 +85,7 @@ def donation_enabled() -> bool:
     place instead of allocating a fresh depth×budget counter pytree per
     batch.  ``REPRO_DONATE=0`` (or ``false``/``off``) restores the copying
     kernels for debugging — bit-identical counters either way, gated by the
-    kill-switch parity test and the A/B cells in ``BENCH_ingest.json``.
+    donation parity tests in ``tests/test_ingest_fastpath.py``.
     """
     return os.environ.get("REPRO_DONATE", "1").strip().lower() \
         not in ("0", "false", "off")

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import countmin, kmatrix
+from repro.core import countmin, kmatrix, kmatrix_accel
 from repro.core.types import EdgeBatch
 from repro.runtime import QueueItem, Runtime
 from repro.runtime.worker import IngestWorker, _item_nbytes, preaggregate_edges
@@ -312,6 +312,65 @@ def test_dedup_runtime_bit_identical_and_counts_compression():
     assert rep0.get("dedup_ratio") is None
     assert rep1["dedup_ratio"] >= 1.0
     assert rep1["dedup_unique_rows"] <= rep1["dedup_raw_rows"]
+
+
+@pytest.fixture(scope="module")
+def accel_groups():
+    """A small width-class sketch over skewed email-EuAll (banded plan)
+    three coalesced groups of its stream, as the worker builds them, and
+    the plain arm's snapshot (no donation, no dedup)."""
+    from repro.core import KMatrixAccel, vertex_stats_from_sample
+    from repro.streams import make_stream, sample_stream
+
+    stream = make_stream("email-EuAll", batch_size=1024, seed=5, scale=0.05)
+    stats = vertex_stats_from_sample(*sample_stream(stream, 3000, seed=7))
+    sk = KMatrixAccel.create(bytes_budget=64 * 1024, stats=stats, depth=3,
+                             seed=3, partitioner="banded")
+    groups = []
+    for g in range(3):
+        cols = [stream.batch_numpy(4 * g + k) for k in range(4)]
+        groups.append(tuple(np.ascontiguousarray(
+            np.concatenate([c[j] for c in cols]), np.int32)
+            for j in range(3)))
+    plain = _accel_arm(sk, groups, donate=False, dedup=False)
+    return sk, groups, plain
+
+
+def _accel_arm(sk, groups, *, donate, dedup):
+    buf = SnapshotBuffer(sk, kmatrix_accel, tenant_id="t", donate=donate)
+    assert buf.donate == donate
+    for src, dst, w in groups:
+        if dedup:
+            us, ud, uw = preaggregate_edges(src, dst, w)
+            assert us.shape[0] < src.shape[0]  # the stream has duplicates
+            # zero-weight padding to the raw length: one dispatch shape
+            pad = [np.zeros_like(src) for _ in range(3)]
+            for col, part in zip(pad, (us, ud, uw)):
+                col[:part.shape[0]] = part
+            buf.ingest(EdgeBatch.from_numpy(*pad),
+                       count=int(np.count_nonzero(w)))
+        else:
+            buf.ingest(EdgeBatch.from_numpy(src, dst, w))
+    return buf.publish()
+
+
+@pytest.mark.parametrize("dedup", [False, True])
+@pytest.mark.parametrize("donate", [False, True])
+def test_width_class_fast_path_arms_are_bit_identical(accel_groups, donate,
+                                                      dedup):
+    """Donation x dedup on the width-class layout: every arm publishes the
+    counters and ``n_edges`` of the plain arm (no donation, no dedup), and
+    the plain arm's are those of an exact scatter of the raw groups."""
+    sk, groups, plain = accel_groups
+    snap = _accel_arm(sk, groups, donate=donate, dedup=dedup)
+    raw = sum(int(np.count_nonzero(g[2])) for g in groups)
+    assert snap.n_edges == plain.n_edges == raw
+    assert layout_counters_equal(snap.sketch, plain.sketch)
+    flat = kmatrix_accel.to_flat_layout(kmatrix_accel.empty_like(sk))
+    for g in groups:
+        flat = kmatrix.ingest(flat, EdgeBatch.from_numpy(*g))
+    assert layout_counters_equal(
+        kmatrix_accel.to_flat_layout(plain.sketch), flat)
 
 
 @pytest.mark.slow

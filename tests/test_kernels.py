@@ -9,14 +9,11 @@ try:
 except ModuleNotFoundError:  # images without hypothesis: skip, don't die
     from _hypothesis_stub import given, settings, st
 
-from repro.core import EdgeBatch, MatrixSketch, vertex_stats_from_sample
-from repro.core import matrix_sketch
-from repro.kernels import matrix_ingest, matrix_lookup, reach_step, embedding_bag
+from repro.core import EdgeBatch, vertex_stats_from_sample
+from repro.kernels import matrix_ingest, reach_step, embedding_bag
 from repro.kernels import ref
 from repro.kernels.ops import (
     KMatrixAccel,
-    accel_matrix_edge_freq,
-    accel_matrix_ingest,
     accel_reach_closure,
     kmatrix_accel_edge_freq,
     kmatrix_accel_ingest,
@@ -62,35 +59,6 @@ def test_matrix_ingest_property(seed):
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(ref.matrix_ingest_ref(pool, hi, hj, wt))
     )
-
-
-# ---------------------------------------------------------------- lookup --
-@pytest.mark.parametrize("d,p,w,c,tq", [
-    (1, 1, 8, 32, 32),
-    (4, 1, 64, 128, 64),
-    (3, 2, 32, 64, 32),
-])
-def test_matrix_lookup_matches_ref(d, p, w, c, tq):
-    rng = np.random.default_rng(w + c)
-    pool = jnp.asarray(rng.integers(0, 100, (d, p, w, w)), jnp.int32)
-    hi = jnp.asarray(rng.integers(0, w, (d, p, c)), jnp.int32)
-    hj = jnp.asarray(rng.integers(0, w, (d, p, c)), jnp.int32)
-    out = matrix_lookup(pool, hi, hj, block_q=tq, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(ref.matrix_lookup_ref(pool, hi, hj))
-    )
-
-
-def test_ingest_then_lookup_roundtrip():
-    d, p, w, c = 3, 1, 32, 128
-    rng = np.random.default_rng(9)
-    hi = jnp.asarray(rng.integers(0, w, (d, p, c)), jnp.int32)
-    hj = jnp.asarray(rng.integers(0, w, (d, p, c)), jnp.int32)
-    wt = jnp.ones((p, c), jnp.int32)
-    pool = matrix_ingest(jnp.zeros((d, p, w, w), jnp.int32), hi, hj, wt,
-                         block_b=64, interpret=True)
-    est = matrix_lookup(pool, hi, hj, block_q=64, interpret=True)
-    assert (np.asarray(est) >= 1).all()  # one-sided
 
 
 # --------------------------------------------------------------- closure --
@@ -197,42 +165,6 @@ def test_embedding_bag_weighted():
 
 
 # ------------------------------------------------- end-to-end ops layer ---
-def test_accel_matrix_sketch_equals_core():
-    """Pallas path and pure-JAX core produce IDENTICAL sketch states."""
-    rng = np.random.default_rng(5)
-    sk = MatrixSketch.create(bytes_budget=1 << 14, depth=3, seed=2)
-    src = rng.integers(0, 500, 700).astype(np.int32)
-    dst = rng.integers(0, 500, 700).astype(np.int32)
-    w = rng.integers(1, 4, 700).astype(np.int32)
-    batch = EdgeBatch.from_numpy(src, dst, w)
-    core_state = matrix_sketch.ingest(sk, batch)
-    accel_state = accel_matrix_ingest(sk, batch, block_b=128)
-    np.testing.assert_array_equal(
-        np.asarray(core_state.table), np.asarray(accel_state.table)
-    )
-    qs, qd = jnp.asarray(src[:100]), jnp.asarray(dst[:100])
-    np.testing.assert_array_equal(
-        np.asarray(matrix_sketch.edge_freq(core_state, qs, qd)),
-        np.asarray(accel_matrix_edge_freq(accel_state, qs, qd, block_q=128)),
-    )
-
-
-def test_accel_matrix_sketch_equals_core_at_unaligned_width():
-    """A MatrixSketch whose width no row block divides (w = 520) still
-    counts every edge in every row, the last block's included."""
-    rng = np.random.default_rng(6)
-    sk = MatrixSketch.create(bytes_budget=4 * 520 * 520, depth=1, seed=3)
-    assert sk.w == 520
-    src = rng.integers(0, 5000, 600).astype(np.int32)
-    dst = rng.integers(0, 5000, 600).astype(np.int32)
-    w = rng.integers(1, 4, 600).astype(np.int32)
-    batch = EdgeBatch.from_numpy(src, dst, w)
-    core = np.asarray(matrix_sketch.ingest(sk, batch).table)
-    accel = np.asarray(accel_matrix_ingest(sk, batch, block_b=128).table)
-    assert core[:, 264:].sum() > 0  # the second row block is exercised
-    np.testing.assert_array_equal(core, accel)
-
-
 def test_kmatrix_accel_exact_counting():
     """Class-layout ingest (dispatch + kernel + overflow) never loses edges."""
     rng = np.random.default_rng(11)

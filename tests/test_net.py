@@ -287,6 +287,43 @@ def test_leaf_codec_sparse_dense_adaptive_and_exact():
                              np.array([1], np.int64))])
 
 
+@pytest.mark.parametrize("backend", ["flat", "pallas"])
+def test_delta_publish_frame_is_smaller_and_folds_to_the_front(backend):
+    """One publish encoded both ways through the leaf codec: the captured
+    per-epoch delta ships in a nonempty frame smaller than the whole front's
+    (which is no larger than the raw leaves a full publish ships), and the
+    decoded delta merged into the previous front is the published front."""
+    t = _registry(sketch_backend=backend).open("cit-HepPh", "kmatrix", 64,
+                                               seed=0)
+    t.buffer.capture_publish_delta = True
+    t.step(4)
+    prev = t.publish()
+    t.step(1)
+    snap = t.publish()
+    assert snap.epoch == prev.epoch + 1
+    assert hasattr(snap.sketch, "pools") == (backend == "pallas")
+
+    def frame(leaves):
+        return wire.encode_message(("publish", {
+            "epoch": snap.epoch, "n_edges": snap.n_edges,
+            "leaves": wire.encode_leaves(
+                [np.asarray(x) for x in leaves])}))
+
+    delta_leaves = jax.tree_util.tree_leaves(t.buffer.last_publish_delta)
+    front_leaves, treedef = jax.tree_util.tree_flatten(snap.sketch)
+    delta_frame, full_frame = frame(delta_leaves), frame(front_leaves)
+    assert 0 < len(delta_frame) < len(full_frame)
+
+    payload = wire.decode_message(delta_frame)[1]
+    delta = jax.tree_util.tree_unflatten(
+        treedef, [jax.numpy.asarray(x)
+                  for x in wire.decode_leaves(payload["leaves"])])
+    folded = jax.tree_util.tree_leaves(t.mod.merge(prev.sketch, delta))
+    assert len(folded) == len(front_leaves)
+    for got, want in zip(folded, front_leaves):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # --------------------------------------------------------- wire security
 def test_wire_restricted_unpickler_blocks_code_execution():
     """A crafted frame whose pickle names an executable global (the classic

@@ -28,12 +28,10 @@ from repro.obs import (
     get_trace_log,
     hist_summary,
     merge_hist_states,
-    new_trace_id,
     quantile_from_state,
     render_prometheus,
     reset_hub,
     reset_trace_log,
-    set_disabled,
 )
 from repro.obs.dashboard import parse_prometheus_text
 from repro.runtime.metrics import RateEWMA, WorkerMetrics
@@ -43,9 +41,7 @@ from repro.runtime.metrics import RateEWMA, WorkerMetrics
 def _fresh_telemetry():
     reset_hub()
     reset_trace_log()
-    set_disabled(False)
     yield
-    set_disabled(False)
     reset_hub()
     reset_trace_log()
 
@@ -170,20 +166,6 @@ def test_prometheus_parser_is_strict():
         parse_prometheus_text("not a metric line at all")
     with pytest.raises(ValueError):
         parse_prometheus_text('x{bad-label="1"} 2')
-
-
-def test_set_disabled_is_a_global_kill_switch():
-    set_disabled(True)
-    hub = get_hub()
-    hub.counter("c", "c").inc(5)
-    hub.histogram("h", "h").observe(1.0)
-    get_trace_log().emit(new_trace_id(), "ingest", "enqueue")
-    state = hub.state()
-    assert [v for _, _, v in state["counters"]] == [0.0]
-    assert get_trace_log().emitted == 0
-    set_disabled(False)
-    hub.counter("c", "c").inc(5)
-    assert [v for _, _, v in hub.state()["counters"]] == [5.0]
 
 
 # ---------------------------------------------------- runtime satellites
@@ -457,19 +439,6 @@ def test_span_ring_keeps_end_order_bounds_and_counts_drops(monkeypatch):
     assert [s.t1_ns for s in spans] == sorted(s.t1_ns for s in spans)
     log.clear()
     assert log.spans() == [] and log.spans_dropped == 0
-
-
-def test_span_honours_the_metrics_kill_switch():
-    log = get_trace_log()
-    set_disabled(True)
-    ran = []
-    with log.span("kmatrix.test.off", key=1):
-        ran.append(1)
-    assert ran == [1] and log.spans() == []
-    set_disabled(False)
-    with log.span("kmatrix.test.on", key=2):
-        pass
-    assert [(s.name, s.key) for s in log.spans()] == [("kmatrix.test.on", 2)]
 
 
 def test_nested_spans_share_a_key_and_nest_in_time():

@@ -183,9 +183,11 @@ def test_sharded_reach_closure_cache_keys_on_epoch_vector():
 
 
 # ------------------------------------------------------- runtime + restore
-def test_sharded_runtime_drain_conserves_across_shards():
+@pytest.mark.parametrize("n_shards", [1, 2, 3, 4])
+def test_sharded_runtime_drain_conserves_across_shards(n_shards):
     reg = _registry()
-    st = reg.open_sharded("cit-HepPh", "kmatrix", 64, seed=0, n_shards=3)
+    st = reg.open_sharded("cit-HepPh", "kmatrix", 64, seed=0,
+                          n_shards=n_shards)
     rt = Runtime(queue_capacity=4, publish_policy="every:2", reservoir_k=0,
                  poll_s=0.01)
     handles = attach_shards(rt, st)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import matrix_ingest, matrix_lookup, reach_step
+from repro.kernels import matrix_ingest, reach_step
 from repro.kernels.matrix_ingest import MAX_WIDTH
 
 
@@ -72,16 +72,6 @@ def test_matrix_ingest_compiles(one_chip, d, p, w, c):
                         _int32((d, p, c), one_chip),
                         _int32((d, p, c), one_chip),
                         _int32((p, c), one_chip)).compile()
-    _assert_kernel(compiled)
-
-
-def test_matrix_lookup_compiles(one_chip):
-    d, p, w, c = 7, 3, 256, 1024
-    fn = jax.jit(lambda pool, hi, hj: matrix_lookup(
-        pool, hi, hj, block_q=256, interpret=False))
-    compiled = fn.lower(_int32((d, p, w, w), one_chip),
-                        _int32((d, p, c), one_chip),
-                        _int32((d, p, c), one_chip)).compile()
     _assert_kernel(compiled)
 
 

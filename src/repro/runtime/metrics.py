@@ -78,6 +78,11 @@ class WorkerMetrics:
     # the scatter-row compression the fast path wins on skewed streams
     dedup_raw_rows: int = 0  # guarded-by: _lock
     dedup_unique_rows: int = 0  # guarded-by: _lock
+    # dedup dispatches, and those whose every weight lies within the ingest
+    # kernel's one-pass bound (matrix_ingest.fits_one_pass), so every width
+    # class of the dispatch takes one bfloat16 MXU pass
+    dedup_dispatches: int = 0  # guarded-by: _lock
+    one_pass_dispatches: int = 0  # guarded-by: _lock
 
     def __post_init__(self) -> None:
         self._lock = threading.Lock()
@@ -89,6 +94,8 @@ class WorkerMetrics:
         self._hub_publish_hist = None
         self._hub_dedup_raw = None
         self._hub_dedup_unique = None
+        self._hub_dedup_dispatches = None
+        self._hub_one_pass = None
 
     def bind_hub(self, tenant_id: str, backend: str = "") -> None:
         """Mirror this worker's counters into typed hub instruments
@@ -117,6 +124,13 @@ class WorkerMetrics:
             "repro_ingest_dedup_unique_rows_total",
             "unique (src,dst) rows dispatched after pre-aggregation",
             **labels)
+        self._hub_dedup_dispatches = hub.counter(
+            "repro_ingest_dedup_dispatches_total",
+            "dispatches of pre-aggregated rows", **labels)
+        self._hub_one_pass = hub.counter(
+            "repro_ingest_one_pass_dispatches_total",
+            "pre-aggregated dispatches whose weights all fit the ingest "
+            "kernel's one bfloat16 pass", **labels)
 
     def note_started(self, now: float) -> None:
         with self._lock:
@@ -149,13 +163,19 @@ class WorkerMetrics:
             self._hub_publishes.inc()
             self._hub_publish_hist.observe(latency_s)
 
-    def note_dedup(self, raw_rows: int, unique_rows: int) -> None:
+    def note_dedup(self, raw_rows: int, unique_rows: int,
+                   one_pass: bool) -> None:
         with self._lock:
             self.dedup_raw_rows += raw_rows
             self.dedup_unique_rows += unique_rows
+            self.dedup_dispatches += 1
+            self.one_pass_dispatches += one_pass
         if self._hub_dedup_raw is not None:
             self._hub_dedup_raw.inc(raw_rows)
             self._hub_dedup_unique.inc(unique_rows)
+            self._hub_dedup_dispatches.inc()
+            if one_pass:
+                self._hub_one_pass.inc()
 
     def note_checkpoint(self, now: float) -> None:
         with self._lock:
@@ -215,6 +235,8 @@ class WorkerMetrics:
                 "dedup_ratio": round(
                     self.dedup_raw_rows / self.dedup_unique_rows, 4)
                 if self.dedup_unique_rows else None,
+                "dedup_dispatches": self.dedup_dispatches,
+                "one_pass_dispatches": self.one_pass_dispatches,
                 # accel-backend scatter-fallback volume (0 on the flat
                 # backend): a rising rate means per-partition dispatch
                 # capacity is being outgrown and ingest is silently paying

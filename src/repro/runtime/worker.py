@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.checkpoint import store
 from repro.core.types import EdgeBatch
+from repro.kernels.matrix_ingest import fits_one_pass
 from repro.obs.trace import get_trace_log
 from repro.runtime.metrics import WorkerMetrics
 from repro.runtime.policies import PublishPolicy
@@ -369,6 +370,7 @@ class IngestWorker(threading.Thread):
                 raw_live = int(np.count_nonzero(np.asarray(rw)))
                 usrc, udst, uw = preaggregate_edges(rs, rd, rw)
             n = usrc.shape[0]
+            one_pass = n == 0 or bool(fits_one_pass(uw))
             count = sum(it.n_edges for it in items)
         else:
             n = sum(it.src.shape[0] for it in items)
@@ -417,7 +419,7 @@ class IngestWorker(threading.Thread):
         for it in items:
             self.metrics.note_ingest(it.n_edges, now)
         if self.dedup:
-            self.metrics.note_dedup(raw_live, n)
+            self.metrics.note_dedup(raw_live, n, one_pass)
         self._batches_since_checkpoint += len(items)
         self._dispatch_seq += 1
 

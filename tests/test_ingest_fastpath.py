@@ -422,3 +422,40 @@ def test_dispatch_granule_is_the_padding_rule(target, monkeypatch):
         worker._ingest_coalesced(
             [QueueItem.from_arrays(-1, src, dst, w)], 0.0)
         assert rows[-1] == max(granule, -(-n // granule) * granule)
+
+
+@pytest.mark.parametrize("weights,one_pass", [
+    ([256, 3], True),
+    ([-256, 1], True),
+    ([1] * 256, True),  # 256 repeats of one edge: pre-aggregated to 256
+    ([257, 3], False),
+    ([-257, 1], False),
+    ([1] * 257, False),
+], ids=["256", "-256", "dup-256", "257", "-257", "dup-257"])
+def test_one_pass_dispatches_counts_the_kernel_rule(weights, one_pass,
+                                                    monkeypatch, request):
+    """A dedup dispatch counts as one-pass exactly when every pre-aggregated
+    weight lies within ``ONE_PASS_MAX_WEIGHT`` (256) of zero, and the count
+    reaches the snapshot and the hub's exposition."""
+    from repro.obs.hub import get_hub
+    from repro.runtime import BoundedEdgeQueue, make_policy
+
+    t = _registry().open("cit-HepPh", "kmatrix", 64, seed=0)
+    worker = IngestWorker(t, BoundedEdgeQueue(4), make_policy("every:1"),
+                          dedup=True)
+    worker.metrics.bind_hub(request.node.name)  # labels no other test uses
+    monkeypatch.setattr(t.buffer, "ingest", lambda batch, count=None: None)
+    w = np.asarray(weights, np.int32)
+    repeats = w.size > 2
+    src = np.zeros(w.size, np.int32) if repeats else np.arange(w.size,
+                                                              dtype=np.int32)
+    dst = np.full(w.size, 9, np.int32)
+    worker._ingest_coalesced([QueueItem.from_arrays(-1, src, dst, w)], 0.0)
+    snap = worker.metrics_snapshot()
+    assert snap["dedup_dispatches"] == 1
+    assert snap["one_pass_dispatches"] == int(one_pass)
+    counters = {n: v for n, labels, v in get_hub().state()["counters"]
+                if labels.get("tenant") == request.node.name}
+    assert counters["repro_ingest_dedup_dispatches_total"] == 1
+    assert counters["repro_ingest_one_pass_dispatches_total"] == \
+        int(one_pass)

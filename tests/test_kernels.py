@@ -61,6 +61,68 @@ def test_matrix_ingest_property(seed):
     )
 
 
+def _poisoned_kernel(hi_ref, hj_ref, wt_ref, pool_ref, out_ref):
+    """A kernel body whose result matches no reference: the branch that
+    must not run is swapped for it."""
+    out_ref[...] = jnp.full(out_ref.shape, -1, out_ref.dtype)
+
+
+def _one_pass_case(name, rng, d, p, w, c):
+    """Weights (P, C) of one case of the one-pass branch's edge cases."""
+    wt = rng.integers(-256, 257, (p, c))
+    if name == "bounds":
+        wt[0, :2] = 256, -256
+    elif name == "over":
+        wt[0, 0] = 257
+    elif name == "under":
+        wt[-1, -1] = -257
+    return wt
+
+
+@pytest.mark.parametrize("case,d,p,w,c,one_pass", [
+    ("bounds", 2, 2, 16, 256, True),
+    ("over", 2, 2, 16, 256, False),
+    ("under", 2, 2, 16, 256, False),
+    ("tile-sum", 1, 1, 16, 256, True),
+    ("sx-256", 2, 3, 256, 128, True),
+    ("sx-512", 2, 1, 512, 128, True),
+    ("sx-1024", 1, 1, 1024, 128, True),  # row-blocked: 4 blocks of 256
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_matrix_ingest_one_pass_branch(monkeypatch, case, d, p, w, c,
+                                       one_pass):
+    """Weights within [-256, 256] take the one bfloat16 pass and weights
+    past it the fp32 contraction, both bit-exact against the reference;
+    the branch that must not run is poisoned, so the match also shows which
+    branch ran.  ``tile-sum`` puts 128 edges of weight 256 on one cell of
+    one 128-edge tile: an increment of 2^15."""
+    import importlib
+
+    mi = importlib.import_module("repro.kernels.matrix_ingest")
+    rng = np.random.default_rng(w + c)
+    pool = jnp.asarray(rng.integers(-1000, 1000, (d, p, w, w)), jnp.int32)
+    hi = rng.integers(0, w, (d, p, c))
+    hj = rng.integers(0, w, (d, p, c))
+    if case == "tile-sum":
+        wt = np.zeros((p, c), np.int64)
+        wt[:, :128] = 256
+        hi[:, :, :128], hj[:, :, :128] = w - 1, 3
+    else:
+        wt = _one_pass_case(case, rng, d, p, w, c)
+    hi, hj, wt = (jnp.asarray(a, jnp.int32) for a in (hi, hj, wt))
+    assert bool(mi.fits_one_pass(wt)) == one_pass
+    idle = "_ingest_kernel" if one_pass else "_ingest_kernel_one_pass"
+    jax.clear_caches()
+    monkeypatch.setattr(mi, idle, _poisoned_kernel)
+    try:
+        out = matrix_ingest(pool, hi, hj, wt, block_b=128, interpret=True)
+    finally:
+        jax.clear_caches()
+    expect = ref.matrix_ingest_ref(pool, hi, hj, wt)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+    if case == "tile-sum":
+        assert int(out[0, 0, w - 1, 3] - pool[0, 0, w - 1, 3]) == 2 ** 15
+
+
 # --------------------------------------------------------------- closure --
 @pytest.mark.parametrize("w,block", [(128, 128), (256, 128), (512, 256)])
 def test_reach_step_matches_ref(w, block):

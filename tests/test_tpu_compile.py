@@ -53,6 +53,14 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_both_passes(compiled, n_classes):
+    """Each class's ingest holds both kernels, the one bfloat16 pass and
+    the fp32 contraction, as the branches of a conditional."""
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * n_classes
+    assert text.count(" conditional(") == n_classes
+
+
 @pytest.mark.parametrize("d,p,w,c", [
     pytest.param(7, 7, 16, 1024, id="7-7-16"),
     pytest.param(7, 1, 512, 1024, id="7-1-512"),
@@ -73,6 +81,8 @@ def test_matrix_ingest_compiles(one_chip, d, p, w, c):
                         _int32((d, p, c), one_chip),
                         _int32((p, c), one_chip)).compile()
     _assert_kernel(compiled)
+    if c == 8192:
+        _assert_both_passes(compiled, 1)
 
 
 @pytest.mark.parametrize("w", [512, 1024])
@@ -126,4 +136,7 @@ def test_kmatrix_accel_ingest_step_compiles(one_chip, monkeypatch, dataset,
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         (sk, stream.batch(0)))
-    _assert_kernel(jax.jit(ops.kmatrix_accel_ingest).lower(*shapes).compile())
+    compiled = jax.jit(ops.kmatrix_accel_ingest).lower(*shapes).compile()
+    _assert_kernel(compiled)
+    if dataset == "sx-stackoverflow":
+        _assert_both_passes(compiled, 3)
